@@ -18,10 +18,7 @@ overload sweep showing the eps degradation ladder engaging) and
 and the pull-loop roofline's bytes-per-pull cells) and
 ``BENCH_PR8.json`` (the fp32/int8/int4/pq precision ladder on a planted
 compressible workload: bytes per pull, total sampling bytes, recall and
-wall time per tier) and ``BENCH_PR9.json`` (observability overhead:
-sustained rps / p99 on the PR-6 bursty workload with instrumentation
-off vs metrics-only vs metrics+trace+flight, plus the ns/op micro price
-of the raw registry calls — gate: <= 3% on both) and ``BENCH_PR10.json``
+wall time per tier) and ``BENCH_PR10.json``
 (multi-tenant serving: per-tenant answered fraction / shed / p99 under
 hot-tenant skew, cold-tenant p99 vs a dedicated isolated baseline — gate:
 ratio <= 2x with the hot tenant throttled not starving — and the
@@ -44,13 +41,12 @@ BENCH5_JSON = os.path.join(_ROOT, "BENCH_PR5.json")
 BENCH6_JSON = os.path.join(_ROOT, "BENCH_PR6.json")
 BENCH7_JSON = os.path.join(_ROOT, "BENCH_PR7.json")
 BENCH8_JSON = os.path.join(_ROOT, "BENCH_PR8.json")
-BENCH9_JSON = os.path.join(_ROOT, "BENCH_PR9.json")
 BENCH10_JSON = os.path.join(_ROOT, "BENCH_PR10.json")
 
 
 def main() -> None:
     from benchmarks import (bench_adaptive, bench_coord, bench_fused,
-                            bench_obs, bench_quant, bench_runtime,
+                            bench_quant, bench_runtime,
                             bench_serve, bench_store, bench_tenancy,
                             fig1_guarantee, fig23_synthetic, fig4_real,
                             roofline, table1_complexity)
@@ -98,11 +94,6 @@ def main() -> None:
     with open(BENCH8_JSON, "w") as f:
         json.dump(payload8, f, indent=2)
     print(f"[bench] wrote {BENCH8_JSON}")
-    print("== observability overhead: off vs metrics vs trace (PR 9) ==")
-    payload9 = {"meta": meta, "benchmarks": bench_obs.run()}
-    with open(BENCH9_JSON, "w") as f:
-        json.dump(payload9, f, indent=2)
-    print(f"[bench] wrote {BENCH9_JSON}")
     print("== multi-tenant fairness / paging / isolation (PR 10) ==")
     payload10 = {"meta": meta, "benchmarks": bench_tenancy.run()}
     with open(BENCH10_JSON, "w") as f:

@@ -623,6 +623,7 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
         out_shape=tuple(out_shape),
         compiler_params=params,
         interpret=interpret,
+        name="fused_cascade",       # the kernel's name in device traces
     )(*scalars, *operands)
     ids, vals = out[0].reshape(B, K), out[1].reshape(B, K)
     return (ids, vals, out[2]) if adaptive else (ids, vals)

@@ -36,6 +36,7 @@ meeting the recorded ``eps_served``, or ``rejected``/``overloaded``/
 from __future__ import annotations
 
 import collections
+import contextvars
 import dataclasses
 import struct
 import time
@@ -50,6 +51,7 @@ from repro.launch.admission import (AdmissionController, DegradationLadder,
                                     PriorityClass, ServeResult, Ticket)
 from repro.obs.metrics import (PULL_FRAC_BUCKETS, MetricsRegistry,
                                summarize_latencies)
+from repro.obs.trace import counting_compiles, phase
 
 __all__ = ["QuantizedLRU", "CascadeExecutor", "MIPSServeEngine",
            "ServeRuntime", "DispatchFailed", "dispatch_with_retries"]
@@ -68,6 +70,13 @@ class DispatchFailed(RuntimeError):
         self.cause = cause
         self.retries = retries
         self.backoff = backoff
+
+
+#: the runtime's index of the dispatch in flight, which the executor's
+#: ``repro.executor.*`` spans carry (-1 outside `dispatch_with_retries`);
+#: a context variable, so that ``dispatch(Qbuf, key)`` keeps its signature
+#: for every caller and wrapper of it
+_DIDX = contextvars.ContextVar("repro_didx", default=-1)
 
 
 def dispatch_with_retries(ex, Qbuf, key, *, didx: int, injector=None,
@@ -90,23 +99,27 @@ def dispatch_with_retries(ex, Qbuf, key, *, didx: int, injector=None,
     """
     attempt = 0
     backoff = 0.0
-    while True:
-        injected = (injector.dispatch_error(didx, attempt)
-                    if injector is not None else None)
-        try:
-            if injected is not None:
-                raise injected
-            ids, scores, rounds, dt = ex.dispatch(Qbuf, key)
-            break
-        except Exception as e:
-            if on_error is not None:
-                on_error(e, attempt, injected is not None)
-            if attempt >= max_retries:
-                raise DispatchFailed(e, attempt, backoff) from e
-            if on_retry is not None:
-                on_retry(attempt, backoff)
-            backoff += retry_backoff_s * (2.0 ** attempt)
-            attempt += 1
+    token = _DIDX.set(didx)
+    try:
+        while True:
+            injected = (injector.dispatch_error(didx, attempt)
+                        if injector is not None else None)
+            try:
+                if injected is not None:
+                    raise injected
+                ids, scores, rounds, dt = ex.dispatch(Qbuf, key)
+                break
+            except Exception as e:
+                if on_error is not None:
+                    on_error(e, attempt, injected is not None)
+                if attempt >= max_retries:
+                    raise DispatchFailed(e, attempt, backoff) from e
+                if on_retry is not None:
+                    on_retry(attempt, backoff)
+                backoff += retry_backoff_s * (2.0 ** attempt)
+                attempt += 1
+    finally:
+        _DIDX.reset(token)
     spike = injector.latency_s(didx) if injector is not None else 0.0
     return ids, scores, rounds, dt + spike + backoff, attempt, backoff, spike
 
@@ -313,8 +326,12 @@ class CascadeExecutor:
         self._h_dispatch = self.metrics.histogram(
             "cascade_dispatch_ms",
             "Measured blocking compute time per dispatch (ms).", keys)
+        self._c_compiles = self.metrics.counter(
+            "cascade_compiles_total",
+            "XLA compiles run inside a dispatch's launch.", keys)
         self._c_dispatch.seed(**lbl)
         self._c_recal.seed(**lbl)
+        self._c_compiles.seed(**lbl)
         self._seen_version = (0 if self.store is None
                               else self.store.version)
         self._table_np = None   # host copy, materialized only for recall
@@ -495,20 +512,33 @@ class CascadeExecutor:
         measured blocking compute time, which virtual-clock drivers add
         to their clock.  Raises whatever the dispatch raises — callers
         (the runtime's retry wrapper) own failure policy.
+
+        Each step is a `repro.obs.trace.phase` span carrying the
+        runtime's ``didx``: ``executor.h2d`` (query buffer to the
+        device), ``executor.launch`` (the jitted flush call, where any
+        compile lands and is counted in ``cascade_compiles_total``),
+        ``executor.wait`` (``block_until_ready``) and ``executor.d2h``
+        (results to the host).  ``seconds`` spans the first three.
         """
+        didx = _DIDX.get()
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             # CPU backends warn that donation is unimplemented; harmless
             warnings.filterwarnings("ignore",
                                     message=".*[Dd]onat.*")
-            ids, scores, rounds = self._fn(
-                *self._flush_args(jnp.asarray(Qbuf), key))
-            jax.block_until_ready(scores)
+            with phase("executor.h2d", didx=didx):
+                args = self._flush_args(jnp.asarray(Qbuf), key)
+            with phase("executor.launch", didx=didx), \
+                    counting_compiles(self._c_compiles, **self._mlabels):
+                ids, scores, rounds = self._fn(*args)
+            with phase("executor.wait", didx=didx):
+                jax.block_until_ready(scores)
         dt = time.perf_counter() - t0
         self._c_dispatch.inc(**self._mlabels)
         self._h_dispatch.observe(dt * 1e3, **self._mlabels)
-        return (np.asarray(ids), np.asarray(scores),
-                None if rounds is None else np.asarray(rounds), dt)
+        with phase("executor.d2h", didx=didx):
+            return (np.asarray(ids), np.asarray(scores),
+                    None if rounds is None else np.asarray(rounds), dt)
 
     @property
     def table(self):
@@ -1369,8 +1399,14 @@ class ServeRuntime:
         immediately at eps_served = eps) -> bounded priority queue.
         Refused requests get their typed `ServeResult` immediately;
         admitted ones resolve at a later `poll`/`drain`.  ``cls`` names a
-        configured `PriorityClass` (None = default).
+        configured `PriorityClass` (None = default).  The call is one
+        ``serve.submit`` phase span (`repro.obs.trace.phase`) carrying
+        the request's ``rid``.
         """
+        with phase("serve.submit", rid=self._next_id):
+            return self._admit(q, now, cls)
+
+    def _admit(self, q, now: Optional[float], cls: Optional[str]) -> int:
         now = time.perf_counter() if now is None else now
         rid = self._next_id
         self._next_id += 1
@@ -1449,12 +1485,14 @@ class ServeRuntime:
         compile time, which expires every queued deadline and reads as a
         (spurious) overload.  Legacy counters and stats are untouched
         (the executor-level ``cascade_*`` metrics do count warmup
-        dispatches — by design, so compile cost is visible).
+        dispatches — by design, so compile cost is visible).  The key is
+        folded as a dispatch folds it, so that compiles too.
         """
         t0 = time.perf_counter()
         Qbuf = np.zeros((self.lanes, self.N), np.float32)
+        key = jax.random.fold_in(self._key, 0)
         for ex in self._rung_execs:
-            ex.dispatch(Qbuf, self._key)
+            ex.dispatch(Qbuf, key)
         return time.perf_counter() - t0
 
     # ---- updates ----------------------------------------------------------
@@ -1531,48 +1569,54 @@ class ServeRuntime:
         re-waiting the batch deadline).  Expired-deadline tickets are
         shed during batch assembly.  ``busy_s`` is virtual compute time
         (measured + injected + retry backoff) for virtual-clock drivers.
+        The pass is one ``serve.poll`` phase span.
         """
-        now = time.perf_counter() if now is None else now
-        self.apply_updates(now)
-        done: List[int] = []
-        busy = 0.0
-        while self.admission.depth:
-            t = now + busy
-            oldest = self.admission.oldest_submit()
-            full = self.admission.depth >= self.lanes
-            aged = (oldest is not None
-                    and t - oldest >= self.batch_wait_s)
-            if not (full or aged or busy > 0.0):
-                break
-            batch, expired = self.admission.take(t, self.lanes)
-            for tk, res in expired:
-                if self.flight is not None:
-                    self.flight.record("deadline_expired", t,
-                                       rid=tk.req_id, cls=tk.cls.name)
-                self._finish(tk.req_id, res, t=t)
-                done.append(tk.req_id)
-            if not batch:
-                continue
-            served, dt = self._dispatch(batch, t)
-            done.extend(served)
-            busy += dt
-        return done, busy
+        with phase("serve.poll"):
+            now = time.perf_counter() if now is None else now
+            self.apply_updates(now)
+            done: List[int] = []
+            busy = 0.0
+            while self.admission.depth:
+                t = now + busy
+                oldest = self.admission.oldest_submit()
+                full = self.admission.depth >= self.lanes
+                aged = (oldest is not None
+                        and t - oldest >= self.batch_wait_s)
+                if not (full or aged or busy > 0.0):
+                    break
+                batch, expired = self.admission.take(t, self.lanes)
+                for tk, res in expired:
+                    if self.flight is not None:
+                        self.flight.record("deadline_expired", t,
+                                           rid=tk.req_id, cls=tk.cls.name)
+                    self._finish(tk.req_id, res, t=t)
+                    done.append(tk.req_id)
+                if not batch:
+                    continue
+                served, dt = self._dispatch(batch, t)
+                done.extend(served)
+                busy += dt
+            return done, busy
 
     def drain(self, now: Optional[float] = None) -> Tuple[List[int], float]:
-        """Serve everything queued regardless of triggers or deadlines."""
-        now = time.perf_counter() if now is None else now
-        self.apply_updates(now)
-        done: List[int] = []
-        busy = 0.0
-        while self.admission.depth:
-            batch, _ = self.admission.take(now + busy, self.lanes,
-                                           expire=False)
-            if not batch:
-                break
-            served, dt = self._dispatch(batch, now + busy)
-            done.extend(served)
-            busy += dt
-        return done, busy
+        """Serve everything queued regardless of triggers or deadlines.
+
+        One ``serve.poll`` phase span, like `poll`.
+        """
+        with phase("serve.poll"):
+            now = time.perf_counter() if now is None else now
+            self.apply_updates(now)
+            done: List[int] = []
+            busy = 0.0
+            while self.admission.depth:
+                batch, _ = self.admission.take(now + busy, self.lanes,
+                                               expire=False)
+                if not batch:
+                    break
+                served, dt = self._dispatch(batch, now + busy)
+                done.extend(served)
+                busy += dt
+            return done, busy
 
     # ---- dispatch ----------------------------------------------------------
 
@@ -1607,32 +1651,37 @@ class ServeRuntime:
 
     def _dispatch(self, batch: List[Ticket],
                   t: float) -> Tuple[List[int], float]:
-        # rung from overload pressure at assembly, the max of two signals:
-        # queue depth (the taken batch counts: it was queue content a
-        # moment ago) and *urgency* — the fraction of its deadline budget
-        # the most-delayed batch member has already burned.  Depth alone
-        # misses overload under tight deadlines (requests expire before
-        # the queue builds); urgency alone misses it when deadlines are
-        # infinite.  Either saturating climbs the ladder.
-        load = (self.admission.depth + len(batch)) \
-            / self.admission.queue_capacity
-        urgency = 0.0
-        for tk in batch:
-            budget = tk.t_deadline - tk.t_submit
-            if np.isfinite(budget) and budget > 0:
-                urgency = max(urgency, (t - tk.t_submit) / budget)
-        rung = self.ladder.rung(max(load, urgency))
-        ex = self._rung_execs[rung]
-        Qbuf = np.zeros((self.lanes, self.N), np.float32)
-        for i, tk in enumerate(batch):
-            Qbuf[i] = tk.q
-        # fold on the plain dispatch sequence, NOT a registry counter:
-        # sampling keys must be invariant to observability wiring
-        key = jax.random.fold_in(self._key, self._dispatch_seq)
+        # phase spans: serve.assemble up to the executor call (rung, lane
+        # buffer, key), the executor's own spans, then serve.finish
         didx = self._dispatch_seq
-        self._dispatch_seq += 1
-        self._c_dispatches.inc(
-            filled="full" if len(batch) == self.lanes else "partial")
+        with phase("serve.assemble", didx=didx):
+            # rung from overload pressure at assembly, the max of two
+            # signals: queue depth (the taken batch counts: it was queue
+            # content a moment ago) and *urgency* — the fraction of its
+            # deadline budget the most-delayed batch member has already
+            # burned.  Depth alone misses overload under tight deadlines
+            # (requests expire before the queue builds); urgency alone
+            # misses it when deadlines are infinite.  Either saturating
+            # climbs the ladder.
+            load = (self.admission.depth + len(batch)) \
+                / self.admission.queue_capacity
+            urgency = 0.0
+            for tk in batch:
+                budget = tk.t_deadline - tk.t_submit
+                if np.isfinite(budget) and budget > 0:
+                    urgency = max(urgency, (t - tk.t_submit) / budget)
+            rung = self.ladder.rung(max(load, urgency))
+            ex = self._rung_execs[rung]
+            Qbuf = np.zeros((self.lanes, self.N), np.float32)
+            for i, tk in enumerate(batch):
+                Qbuf[i] = tk.q
+            # fold on the plain dispatch sequence, NOT a registry counter:
+            # sampling keys must be invariant to observability wiring
+            key = jax.random.fold_in(self._key, didx)
+            self._dispatch_seq += 1
+            self._c_dispatches.inc(
+                filled="full" if len(batch) == self.lanes else "partial")
+
         def on_error(e, attempt, injected):
             self._c_dispatch_errors.inc()
             if self.flight is not None:
@@ -1658,67 +1707,69 @@ class ServeRuntime:
         except DispatchFailed as df:
             return self._fail_batch(batch, t, df.cause, df.retries,
                                     df.backoff), df.backoff
-        if spike > 0.0 and self.flight is not None:
-            self.flight.record("fault_latency", t, didx=didx,
-                               spike_ms=spike * 1e3)
-        if (self.dispatch_timeout_s is not None
-                and dt > self.dispatch_timeout_s):
-            self._c_slow.inc()
-        ids = ids[:len(batch)]
-        scores = scores[:len(batch)]
-        self._occupancy.append(len(batch))
-        self._h_occupancy.observe(len(batch))
-        from repro.distributed.sharding import dispatch_lane_stats
-        lane = dispatch_lane_stats(
-            None if rounds is None else rounds[:len(batch)],
-            schedule=ex.plan.schedule, lanes=self.lanes,
-            filled=len(batch))
-        self._pull_fracs.append(lane["executed_pull_frac"])
-        self._h_pull_frac.observe(lane["executed_pull_frac"])
-        eps_r = self.ladder.eps_values[rung]
-        self._c_rung.inc(len(batch), rung=str(rung))
-        if self.tracer is not None:
-            args = {"didx": didx, "rung": rung, "eps_served": eps_r,
-                    "occupancy": len(batch), "retries": attempt,
-                    "pull_frac": lane["executed_pull_frac"]}
-            if spike > 0.0:
-                args["injected_ms"] = spike * 1e3
-            if rounds is not None:
-                args["rounds_used"] = float(
-                    np.mean(rounds[:len(batch)]))
-            self.tracer.global_span(f"dispatch {didx}", t, t + dt, **args)
-        done = []
-        for i, tk in enumerate(batch):
-            out_ids = ex.external_ids(ids[i])
-            self._h_queue_wait.observe((t - tk.t_submit) * 1e3)
+        with phase("serve.finish", didx=didx):
+            if spike > 0.0 and self.flight is not None:
+                self.flight.record("fault_latency", t, didx=didx,
+                                   spike_ms=spike * 1e3)
+            if (self.dispatch_timeout_s is not None
+                    and dt > self.dispatch_timeout_s):
+                self._c_slow.inc()
+            ids = ids[:len(batch)]
+            scores = scores[:len(batch)]
+            self._occupancy.append(len(batch))
+            self._h_occupancy.observe(len(batch))
+            from repro.distributed.sharding import dispatch_lane_stats
+            lane = dispatch_lane_stats(
+                None if rounds is None else rounds[:len(batch)],
+                schedule=ex.plan.schedule, lanes=self.lanes,
+                filled=len(batch))
+            self._pull_fracs.append(lane["executed_pull_frac"])
+            self._h_pull_frac.observe(lane["executed_pull_frac"])
+            eps_r = self.ladder.eps_values[rung]
+            self._c_rung.inc(len(batch), rung=str(rung))
             if self.tracer is not None:
-                self.tracer.span(tk.req_id, "queued", tk.t_submit, t,
-                                 didx=didx)
-                self.tracer.span(tk.req_id, "serve", t, t + dt,
-                                 rung=rung, eps_served=eps_r,
-                                 retries=attempt, didx=didx)
-            res = ServeResult(
-                status="ok" if rung == 0 else "degraded",
-                ids=out_ids, scores=scores[i].copy(),
-                eps_served=eps_r, delta_served=self._delta,
-                cls=tk.cls.name, latency_s=(t + dt) - tk.t_submit,
-                retries=attempt)
-            self._finish(tk.req_id, res, t=t + dt)
-            # only full-quality answers are cacheable: a degraded
-            # (eps_served > eps) result must never be replayed to a
-            # later query as if it met the contract eps
-            if rung == 0 and tk.cache_key is not None:
-                self.cache.put(self._salted(tk.cache_key),
-                               (out_ids, scores[i].copy()))
-            if (self._recall_rate > 0.0
-                    and self._recall_rng.random() < self._recall_rate):
-                self._recalls.append(ex.recall_of(tk.q, ids[i]))
-            done.append(tk.req_id)
-        for buf_name in ("_occupancy", "_pull_fracs", "_recalls"):
-            buf = getattr(self, buf_name)
-            if len(buf) > 100_000:
-                setattr(self, buf_name, buf[-10_000:])
-        return done, dt
+                args = {"didx": didx, "rung": rung, "eps_served": eps_r,
+                        "occupancy": len(batch), "retries": attempt,
+                        "pull_frac": lane["executed_pull_frac"]}
+                if spike > 0.0:
+                    args["injected_ms"] = spike * 1e3
+                if rounds is not None:
+                    args["rounds_used"] = float(
+                        np.mean(rounds[:len(batch)]))
+                self.tracer.global_span(f"dispatch {didx}", t, t + dt,
+                                        **args)
+            done = []
+            for i, tk in enumerate(batch):
+                out_ids = ex.external_ids(ids[i])
+                self._h_queue_wait.observe((t - tk.t_submit) * 1e3)
+                if self.tracer is not None:
+                    self.tracer.span(tk.req_id, "queued", tk.t_submit, t,
+                                     didx=didx)
+                    self.tracer.span(tk.req_id, "serve", t, t + dt,
+                                     rung=rung, eps_served=eps_r,
+                                     retries=attempt, didx=didx)
+                res = ServeResult(
+                    status="ok" if rung == 0 else "degraded",
+                    ids=out_ids, scores=scores[i].copy(),
+                    eps_served=eps_r, delta_served=self._delta,
+                    cls=tk.cls.name, latency_s=(t + dt) - tk.t_submit,
+                    retries=attempt)
+                self._finish(tk.req_id, res, t=t + dt)
+                # only full-quality answers are cacheable: a degraded
+                # (eps_served > eps) result must never be replayed to a
+                # later query as if it met the contract eps
+                if rung == 0 and tk.cache_key is not None:
+                    self.cache.put(self._salted(tk.cache_key),
+                                   (out_ids, scores[i].copy()))
+                if (self._recall_rate > 0.0 and
+                        self._recall_rng.random() < self._recall_rate):
+                    self._recalls.append(ex.recall_of(tk.q, ids[i]))
+                done.append(tk.req_id)
+            for buf_name in ("_occupancy", "_pull_fracs", "_recalls"):
+                buf = getattr(self, buf_name)
+                if len(buf) > 100_000:
+                    setattr(self, buf_name, buf[-10_000:])
+            return done, dt
 
     # ---- observability -----------------------------------------------------
 

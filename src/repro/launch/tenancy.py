@@ -65,6 +65,7 @@ from repro.launch.engine import (CascadeExecutor, DispatchFailed,
                                  QuantizedLRU, dispatch_with_retries)
 from repro.obs.metrics import (MetricsRegistry, PULL_FRAC_BUCKETS,
                                summarize_latencies)
+from repro.obs.trace import phase
 
 __all__ = ["TenancyError", "TenantConfig", "TableRegistry",
            "MultiTenantRuntime"]
@@ -860,8 +861,9 @@ class MultiTenantRuntime:
             execs, _ = self.registry.executors(name)
             store = self.registry.store(name)
             Qbuf = np.zeros((self.lanes, store.N), np.float32)
+            key = jax.random.fold_in(st.key, 0)
             for ex in execs:
-                ex.dispatch(Qbuf, st.key)
+                ex.dispatch(Qbuf, key)
         return time.perf_counter() - t0
 
     # ---- request path -----------------------------------------------------
@@ -897,8 +899,14 @@ class MultiTenantRuntime:
         routing bug, not traffic).  The query itself never raises: it
         runs the tenant's private admission pipeline (poison validation
         -> quarantine -> version-salted cache -> bounded queue) exactly
-        like a dedicated `ServeRuntime.submit`.
+        like a dedicated `ServeRuntime.submit`, inside one
+        ``serve.submit`` phase span carrying the ``rid``.
         """
+        with phase("serve.submit", rid=self._next_id):
+            return self._admit(q, tenant, now, cls)
+
+    def _admit(self, q, tenant: str, now: Optional[float],
+               cls: Optional[str]) -> int:
         now = time.perf_counter() if now is None else now
         st = self._state(tenant)
         rid = self._next_id
@@ -1025,55 +1033,56 @@ class MultiTenantRuntime:
         so arrival skew cannot translate into service skew.  Returns
         ``(finished request ids, virtual busy seconds)``.
         """
-        now = time.perf_counter() if now is None else now
-        for name in self.registry.tenants():
-            self.apply_updates(name, now)
-        done: List[int] = []
-        busy = 0.0
-        progress = True
-        while progress:
-            progress = False
-            backlogged = {name: st.admission.depth > 0
-                          for name, st in self._states.items()}
-            if not any(backlogged.values()):
-                break
-            self.drr.start_round(backlogged)
-            for name in self.drr.flows():
-                st = self._states.get(name)
-                if st is None:
-                    continue
-                while st.admission.depth:
-                    t = now + busy
-                    oldest = st.admission.oldest_submit()
-                    full = st.admission.depth >= self.lanes
-                    aged = (oldest is not None
-                            and t - oldest >= self.batch_wait_s)
-                    if not (full or aged or busy > 0.0):
-                        break
-                    allow = self.drr.allowance(name)
-                    if allow < 1:
-                        break
-                    batch, expired = st.admission.take(
-                        t, min(self.lanes, allow))
-                    for tk, res in expired:
-                        if self.flight is not None:
-                            self.flight.record("deadline_expired", t,
-                                               rid=tk.req_id, tenant=name)
-                        self._finish(st, tk.req_id, res, t=t)
-                        done.append(tk.req_id)
-                    if not batch:
-                        if not expired:
-                            break
+        with phase("serve.poll"):
+            now = time.perf_counter() if now is None else now
+            for name in self.registry.tenants():
+                self.apply_updates(name, now)
+            done: List[int] = []
+            busy = 0.0
+            progress = True
+            while progress:
+                progress = False
+                backlogged = {name: st.admission.depth > 0
+                              for name, st in self._states.items()}
+                if not any(backlogged.values()):
+                    break
+                self.drr.start_round(backlogged)
+                for name in self.drr.flows():
+                    st = self._states.get(name)
+                    if st is None:
                         continue
-                    self.drr.consume(name, len(batch))
-                    served, dt = self._dispatch(st, batch, t)
-                    done.extend(served)
-                    busy += dt
-                    progress = True
-                if st.admission.depth == 0:
-                    self.drr.reset(name)
-            self.drr.rotate()
-        return done, busy
+                    while st.admission.depth:
+                        t = now + busy
+                        oldest = st.admission.oldest_submit()
+                        full = st.admission.depth >= self.lanes
+                        aged = (oldest is not None
+                                and t - oldest >= self.batch_wait_s)
+                        if not (full or aged or busy > 0.0):
+                            break
+                        allow = self.drr.allowance(name)
+                        if allow < 1:
+                            break
+                        batch, expired = st.admission.take(
+                            t, min(self.lanes, allow))
+                        for tk, res in expired:
+                            if self.flight is not None:
+                                self.flight.record("deadline_expired", t,
+                                                   rid=tk.req_id, tenant=name)
+                            self._finish(st, tk.req_id, res, t=t)
+                            done.append(tk.req_id)
+                        if not batch:
+                            if not expired:
+                                break
+                            continue
+                        self.drr.consume(name, len(batch))
+                        served, dt = self._dispatch(st, batch, t)
+                        done.extend(served)
+                        busy += dt
+                        progress = True
+                    if st.admission.depth == 0:
+                        self.drr.reset(name)
+                self.drr.rotate()
+            return done, busy
 
     def drain(self, now: Optional[float] = None) -> Tuple[List[int], float]:
         """Serve everything queued, tenant by tenant, ignoring triggers
@@ -1084,23 +1093,24 @@ class MultiTenantRuntime:
         a dedicated engine's, which is what the bit-identity suite
         leans on.
         """
-        now = time.perf_counter() if now is None else now
-        done: List[int] = []
-        busy = 0.0
-        for name in self.registry.tenants():
-            self.apply_updates(name, now)
-            st = self._states.get(name)
-            if st is None:
-                continue
-            while st.admission.depth:
-                batch, _ = st.admission.take(now + busy, self.lanes,
-                                             expire=False)
-                if not batch:
-                    break
-                served, dt = self._dispatch(st, batch, now + busy)
-                done.extend(served)
-                busy += dt
-        return done, busy
+        with phase("serve.poll"):
+            now = time.perf_counter() if now is None else now
+            done: List[int] = []
+            busy = 0.0
+            for name in self.registry.tenants():
+                self.apply_updates(name, now)
+                st = self._states.get(name)
+                if st is None:
+                    continue
+                while st.admission.depth:
+                    batch, _ = st.admission.take(now + busy, self.lanes,
+                                                 expire=False)
+                    if not batch:
+                        break
+                    served, dt = self._dispatch(st, batch, now + busy)
+                    done.extend(served)
+                    busy += dt
+            return done, busy
 
     # ---- dispatch ---------------------------------------------------------
 
@@ -1126,43 +1136,46 @@ class MultiTenantRuntime:
     def _dispatch(self, st: _TenantState, batch: List[Ticket],
                   t: float) -> Tuple[List[int], float]:
         name = st.name
-        load = ((st.admission.depth + len(batch))
-                / st.admission.queue_capacity)
-        urgency = 0.0
-        for tk in batch:
-            budget = tk.t_deadline - tk.t_submit
-            if np.isfinite(budget) and budget > 0:
-                urgency = max(urgency, (t - tk.t_submit) / budget)
-        rung = st.ladder.rung(max(load, urgency))
+        didx = st.dispatch_seq
+        # phase spans as in `ServeRuntime._dispatch`; serve.assemble
+        # also covers the table's page-in
         with self.registry.serving(name):
-            try:
-                execs, page_s = self.registry.executors(name)
-            except TenancyError as e:
-                # residency refusal (e.g. the table grew past what the
-                # budget can rebalance): typed failed results, no
-                # quarantine — the queries were fine, the table wasn't
-                self._c_failed_batches.inc(tenant=name)
-                if self.flight is not None:
-                    self.flight.record("table_unavailable", t,
-                                       tenant=name, error=str(e))
+            with phase("serve.assemble", didx=didx):
+                load = ((st.admission.depth + len(batch))
+                        / st.admission.queue_capacity)
+                urgency = 0.0
                 for tk in batch:
-                    self._finish(st, tk.req_id, ServeResult(
-                        status="failed",
-                        reason=f"table unavailable: {e}",
-                        latency_s=t - tk.t_submit), t=t)
-                return [tk.req_id for tk in batch], 0.0
-            ex = execs[rung]
-            Qbuf = np.zeros((self.lanes, ex.N), np.float32)
-            for i, tk in enumerate(batch):
-                Qbuf[i] = tk.q
-            # per-tenant PRNG stream: fold the tenant's key on its own
-            # dispatch sequence, exactly like a dedicated runtime would
-            key = jax.random.fold_in(st.key, st.dispatch_seq)
-            didx = st.dispatch_seq
-            st.dispatch_seq += 1
-            self._c_dispatches.inc(
-                tenant=name,
-                filled="full" if len(batch) == self.lanes else "partial")
+                    budget = tk.t_deadline - tk.t_submit
+                    if np.isfinite(budget) and budget > 0:
+                        urgency = max(urgency, (t - tk.t_submit) / budget)
+                rung = st.ladder.rung(max(load, urgency))
+                try:
+                    execs, page_s = self.registry.executors(name)
+                except TenancyError as e:
+                    # residency refusal (e.g. the table grew past what the
+                    # budget can rebalance): typed failed results, no
+                    # quarantine — the queries were fine, the table wasn't
+                    self._c_failed_batches.inc(tenant=name)
+                    if self.flight is not None:
+                        self.flight.record("table_unavailable", t,
+                                           tenant=name, error=str(e))
+                    for tk in batch:
+                        self._finish(st, tk.req_id, ServeResult(
+                            status="failed",
+                            reason=f"table unavailable: {e}",
+                            latency_s=t - tk.t_submit), t=t)
+                    return [tk.req_id for tk in batch], 0.0
+                ex = execs[rung]
+                Qbuf = np.zeros((self.lanes, ex.N), np.float32)
+                for i, tk in enumerate(batch):
+                    Qbuf[i] = tk.q
+                # per-tenant PRNG stream: fold the tenant's key on its own
+                # dispatch sequence, exactly like a dedicated runtime would
+                key = jax.random.fold_in(st.key, didx)
+                st.dispatch_seq += 1
+                self._c_dispatches.inc(
+                    tenant=name,
+                    filled="full" if len(batch) == self.lanes else "partial")
 
             def on_error(e, attempt, injected):
                 self._c_dispatch_errors.inc(tenant=name)
@@ -1190,66 +1203,67 @@ class MultiTenantRuntime:
                 return (self._fail_batch(st, batch, t, df.cause,
                                          df.retries, df.backoff),
                         page_s + df.backoff)
-        if spike > 0.0 and self.flight is not None:
-            self.flight.record("fault_latency", t, tenant=name, didx=didx,
-                               spike_ms=spike * 1e3)
-        # page-in is real serving cost: charge it to this dispatch's
-        # virtual busy time so eviction thrash is visible in latency
-        dt += page_s
-        if (self.dispatch_timeout_s is not None
-                and dt > self.dispatch_timeout_s):
-            self._c_slow.inc(tenant=name)
-        ids = ids[:len(batch)]
-        scores = scores[:len(batch)]
-        self._occupancy.append(len(batch))
-        self._h_occupancy.observe(len(batch), tenant=name)
-        from repro.distributed.sharding import dispatch_lane_stats
-        lane = dispatch_lane_stats(
-            None if rounds is None else rounds[:len(batch)],
-            schedule=ex.plan.schedule, lanes=self.lanes,
-            filled=len(batch))
-        self._pull_fracs.append(lane["executed_pull_frac"])
-        self._h_pull_frac.observe(lane["executed_pull_frac"], tenant=name)
-        eps_r = st.ladder.eps_values[rung]
-        self._c_rung.inc(len(batch), tenant=name, rung=str(rung))
-        if self.tracer is not None:
-            args = {"tenant": name, "didx": didx, "rung": rung,
-                    "eps_served": eps_r, "occupancy": len(batch),
-                    "retries": attempt,
-                    "pull_frac": lane["executed_pull_frac"]}
-            if page_s > 0.0:
-                args["page_in_ms"] = page_s * 1e3
-            self.tracer.global_span(f"dispatch {name}/{didx}", t, t + dt,
-                                    **args)
-        done = []
-        for i, tk in enumerate(batch):
-            out_ids = ex.external_ids(ids[i])
-            self._h_queue_wait.observe((t - tk.t_submit) * 1e3,
-                                       tenant=name)
+        with phase("serve.finish", didx=didx):
+            if spike > 0.0 and self.flight is not None:
+                self.flight.record("fault_latency", t, tenant=name, didx=didx,
+                                   spike_ms=spike * 1e3)
+            # page-in is real serving cost: charge it to this dispatch's
+            # virtual busy time so eviction thrash is visible in latency
+            dt += page_s
+            if (self.dispatch_timeout_s is not None
+                    and dt > self.dispatch_timeout_s):
+                self._c_slow.inc(tenant=name)
+            ids = ids[:len(batch)]
+            scores = scores[:len(batch)]
+            self._occupancy.append(len(batch))
+            self._h_occupancy.observe(len(batch), tenant=name)
+            from repro.distributed.sharding import dispatch_lane_stats
+            lane = dispatch_lane_stats(
+                None if rounds is None else rounds[:len(batch)],
+                schedule=ex.plan.schedule, lanes=self.lanes,
+                filled=len(batch))
+            self._pull_fracs.append(lane["executed_pull_frac"])
+            self._h_pull_frac.observe(lane["executed_pull_frac"], tenant=name)
+            eps_r = st.ladder.eps_values[rung]
+            self._c_rung.inc(len(batch), tenant=name, rung=str(rung))
             if self.tracer is not None:
-                self.tracer.span(tk.req_id, "queued", tk.t_submit, t,
-                                 didx=didx)
-                self.tracer.span(tk.req_id, "serve", t, t + dt,
-                                 rung=rung, eps_served=eps_r,
-                                 retries=attempt, didx=didx)
-            res = ServeResult(
-                status="ok" if rung == 0 else "degraded",
-                ids=out_ids, scores=scores[i].copy(),
-                eps_served=eps_r, delta_served=st.config.delta,
-                latency_s=(t + dt) - tk.t_submit, retries=attempt)
-            self._finish(st, tk.req_id, res, t=t + dt)
-            if rung == 0 and tk.cache_key is not None:
-                st.cache.put(st.salted(tk.cache_key),
-                             (out_ids, scores[i].copy()))
-            if (self._recall_rate > 0.0
-                    and self._recall_rng.random() < self._recall_rate):
-                self._recalls.append(ex.recall_of(tk.q, ids[i]))
-            done.append(tk.req_id)
-        for buf_name in ("_occupancy", "_pull_fracs", "_recalls"):
-            buf = getattr(self, buf_name)
-            if len(buf) > 100_000:
-                setattr(self, buf_name, buf[-10_000:])
-        return done, dt
+                args = {"tenant": name, "didx": didx, "rung": rung,
+                        "eps_served": eps_r, "occupancy": len(batch),
+                        "retries": attempt,
+                        "pull_frac": lane["executed_pull_frac"]}
+                if page_s > 0.0:
+                    args["page_in_ms"] = page_s * 1e3
+                self.tracer.global_span(f"dispatch {name}/{didx}", t, t + dt,
+                                        **args)
+            done = []
+            for i, tk in enumerate(batch):
+                out_ids = ex.external_ids(ids[i])
+                self._h_queue_wait.observe((t - tk.t_submit) * 1e3,
+                                           tenant=name)
+                if self.tracer is not None:
+                    self.tracer.span(tk.req_id, "queued", tk.t_submit, t,
+                                     didx=didx)
+                    self.tracer.span(tk.req_id, "serve", t, t + dt,
+                                     rung=rung, eps_served=eps_r,
+                                     retries=attempt, didx=didx)
+                res = ServeResult(
+                    status="ok" if rung == 0 else "degraded",
+                    ids=out_ids, scores=scores[i].copy(),
+                    eps_served=eps_r, delta_served=st.config.delta,
+                    latency_s=(t + dt) - tk.t_submit, retries=attempt)
+                self._finish(st, tk.req_id, res, t=t + dt)
+                if rung == 0 and tk.cache_key is not None:
+                    st.cache.put(st.salted(tk.cache_key),
+                                 (out_ids, scores[i].copy()))
+                if (self._recall_rate > 0.0
+                        and self._recall_rng.random() < self._recall_rate):
+                    self._recalls.append(ex.recall_of(tk.q, ids[i]))
+                done.append(tk.req_id)
+            for buf_name in ("_occupancy", "_pull_fracs", "_recalls"):
+                buf = getattr(self, buf_name)
+                if len(buf) > 100_000:
+                    setattr(self, buf_name, buf[-10_000:])
+            return done, dt
 
     # ---- observability ----------------------------------------------------
 

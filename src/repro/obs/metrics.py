@@ -17,8 +17,8 @@ Design constraints, in order:
    ``tests/test_obs_regression.py`` against a pre-migration golden.
 2. **Hot-path cost is a dict lookup + float add.**  Callers hold the
    metric object and pass labels as kwargs; rows are materialized once
-   and then hit a tuple-keyed dict.  ``benchmarks/bench_obs.py`` pins
-   the end-to-end overhead at <= 3%.
+   and then hit a tuple-keyed dict.  What instrumentation costs on the
+   chip is in docs/OBSERVABILITY.md.
 3. **Zero dependencies.**  Exports are JSON (:meth:`MetricsRegistry.snapshot`)
    and Prometheus text exposition format
    (:meth:`MetricsRegistry.render_prometheus`) — no client libraries.
@@ -408,8 +408,7 @@ class _NullMetric:
 
     ``get``/``total``/``sum``/``count`` read back zeros, so legacy
     property-backed counters report 0 instead of raising — the hard-off
-    switch used by ``benchmarks/bench_obs.py`` to measure the
-    observability-off baseline.
+    switch for metric collection.
     """
 
     kind = "null"
@@ -459,8 +458,7 @@ class NullRegistry(MetricsRegistry):
 
     Pass ``metrics=null_registry()`` to an engine/runtime to disable
     metric collection entirely (legacy counter properties read 0, legacy
-    list-backed latency stats still work).  Used to measure the
-    observability-off baseline in ``benchmarks/bench_obs.py``.
+    list-backed latency stats still work).
     """
 
     _NULL = _NullMetric()

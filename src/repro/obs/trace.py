@@ -1,4 +1,11 @@
-"""Per-request span tracing on the serving stack's virtual clock.
+"""Span tracing of the serving stack, on two clocks.
+
+:func:`phase` marks each layer boundary of the serving path
+(``repro.serve.*``, ``repro.executor.*``) as a host span of the JAX
+profiler, so it shares the device trace's clock; outside a profiler
+session it does nothing.  :func:`counting_compiles` counts the XLA
+compiles run inside a block.  The rest of this module is the
+per-request lifecycle tracer, on the clock its caller passes.
 
 :class:`SpanTracer` records the life of each request through
 ``ServeRuntime`` — ``submit -> admission -> queued -> batch-assembly ->
@@ -10,7 +17,7 @@ Layout: everything lives in pid 1.  Thread 0 is the shared
 executor/dispatch track (complete ``X`` spans per batch dispatch,
 annotated with rung, eps_served, rounds_used, pull fraction and fault
 injections); each sampled request gets its own thread ``TID_REQ_BASE +
-rid`` carrying the request-scoped spans.  Timestamps are the virtual
+rid`` carrying the request-scoped spans.  Timestamps are the caller's
 clock in microseconds (floats — Chrome accepts fractional ``ts``), so a
 trace of a simulated bursty stream reads in real units.
 
@@ -22,7 +29,9 @@ ring of the last ``max_global_events`` spans.
 
 from __future__ import annotations
 
+import contextlib
 import json
+import threading
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -31,14 +40,67 @@ import numpy as np
 #: request tracks start here so tid 0 (dispatch track) stays reserved
 TID_REQ_BASE = 16
 
+#: the `jax.monitoring` duration event JAX records around every XLA
+#: backend compile (a load from the persistent compile cache included)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def phase(name: str, **args: int):
+    """Host span ``repro.<name>`` on the profiler's clock.
+
+    A ``jax.profiler.TraceAnnotation``: inside a profiler session it
+    lands on the host plane of the same ``.xplane.pb`` as the device
+    ops, with ``args`` (plain ints such as ``didx`` or ``rid``) attached;
+    outside one it records nothing.
+    """
+    import jax
+
+    return jax.profiler.TraceAnnotation(f"repro.{name}", **args)
+
+
+_compile_sink = threading.local()
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    sink = getattr(_compile_sink, "target", None)
+    if sink is not None and event == BACKEND_COMPILE_EVENT:
+        counter, labels = sink
+        counter.inc(**labels)
+
+
+@contextlib.contextmanager
+def counting_compiles(counter, **labels: str):
+    """Count the XLA backend compiles this thread runs in the block.
+
+    Each compile JAX reports through `jax.monitoring` while the block is
+    open adds 1 to ``counter`` under ``labels``; an inner block takes
+    the count from an outer one.
+    """
+    global _listening
+    if not _listening:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    outer = getattr(_compile_sink, "target", None)
+    _compile_sink.target = (counter, labels)
+    try:
+        yield
+    finally:
+        _compile_sink.target = outer
+
 
 class SpanTracer:
     """Bounded-memory collector of Chrome trace events (one per run).
 
-    Timestamps are the serving stack's *virtual* clock (seconds,
-    rendered as microsecond ``ts``); the export loads directly in
-    Perfetto.  Per-request tracks are reservoir-sampled past
-    ``max_requests`` so memory stays bounded on long streams.
+    Timestamps are the clock the caller passes as ``now`` (seconds,
+    rendered as microsecond ``ts``): the virtual clock of
+    ``simulate_stream``, or ``time.perf_counter()``.  Its ``dispatch
+    {didx}`` span carries the same ``didx`` as the :func:`phase` spans
+    of that dispatch.  The export loads directly in Perfetto.
+    Per-request tracks are reservoir-sampled past ``max_requests`` so
+    memory stays bounded on long streams.
 
     Typical wiring (done by ``ServeRuntime`` when constructed with
     ``tracer=``)::
