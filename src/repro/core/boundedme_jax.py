@@ -61,12 +61,13 @@ from repro.core.quantize import (f32_tile_dot, measured_quant_err,
                                  pq_encode, pq_tile_dot, pq_train,
                                  quantize_blocks, quantize_tiles,
                                  quantize_tiles_int4, unpack_int4)
-from repro.core.schedule import (Schedule, cert_coeffs, flatten_schedule,
-                                 make_schedule)
+from repro.core.schedule import (FlatSchedule, Schedule, cert_coeffs,
+                                 flatten_schedule, make_schedule)
 
 __all__ = ["BlockedPlan", "make_plan", "choose_pull_mode",
            "measured_plan_quant_err", "make_measured_plan",
-           "bounded_me_blocked", "bounded_me_batched", "bounded_me_decode"]
+           "fused_flat_schedule", "bounded_me_blocked", "bounded_me_batched",
+           "bounded_me_decode"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -436,6 +437,19 @@ def make_measured_plan(V, K: int = 1, eps: float = 0.1, delta: float = 0.05,
     return make_plan(n, N, pull_mode=pull_mode, quant_err=qerr, **kwargs)
 
 
+def fused_flat_schedule(plan: BlockedPlan, *, final_exact: bool,
+                        adaptive: bool = False) -> FlatSchedule:
+    """The flat schedule the fused kernel runs for ``plan``.
+
+    Coverage steps complete the final survivors only on the fp32,
+    non-adaptive path; the quantized tiers and adaptive mode rescore
+    their candidates in fp32 instead (`_fused_call`).
+    """
+    return flatten_schedule(
+        plan.schedule, final_coverage=(final_exact and not adaptive
+                                       and plan.precision == "fp32"))
+
+
 def _fused_call(V4, qb_or_Qb, perm_or_perms, *, plan: BlockedPlan,
                 final_exact: bool, batched: bool, k_out: Optional[int] = None,
                 n_valid=None, vscale=None, qscale=None, codebook=None,
@@ -456,18 +470,17 @@ def _fused_call(V4, qb_or_Qb, perm_or_perms, *, plan: BlockedPlan,
     """
     from repro.kernels import ops as _kops
 
-    quantized = plan.precision != "fp32"
-    flat = flatten_schedule(
-        plan.schedule,
-        final_coverage=final_exact and not quantized and not adaptive)
+    flat = fused_flat_schedule(plan, final_exact=final_exact,
+                               adaptive=adaptive)
     slotcode, rmeta = flat.packed()
-    bpos = jnp.asarray(flat.bpos)
     fn = _kops.fused_cascade_batched if batched else _kops.fused_cascade
-    cols = perm_or_perms[..., bpos] if batched else perm_or_perms[bpos]
+    # the dense phase's round-1 columns, then one column per step
+    cols = perm_or_perms[..., jnp.asarray(flat.col_pos)]
     cert = (jnp.asarray(cert_coeffs(plan.schedule)) if adaptive else None)
     return fn(V4, qb_or_Qb, jnp.asarray(slotcode), jnp.asarray(rmeta), cols,
               n_arms=plan.n, K=plan.K, t_final=flat.t_final,
-              n_final=flat.n_final, k_out=k_out, n_valid=n_valid,
+              n_final=flat.n_final, t_dense=flat.t_dense, k_out=k_out,
+              n_valid=n_valid,
               vscale=vscale, qscale=qscale, codebook=codebook,
               packed_int4=plan.precision == "int4", cert=cert, k_cert=plan.K,
               track_var=adaptive and plan.schedule.bound == "bernstein")
@@ -968,39 +981,36 @@ def _run_decode(V, Q, key, n_valid, Vq=None, vaux=None, *,
                     sums = new
             else:
                 # late rounds: few survivors per query — per-query gather
-                # scans, sequential over the batch to bound the working set
-                if is_pq:
-                    def one(args):
-                        idx_i, Qb_i = args
-                        s0 = jnp.zeros((rnd.n_arms, R), jnp.float32)
-                        s20 = jnp.zeros_like(s0) if track_var else None
-                        return _scan_pulls(s0, Vq, Qb_i, idx_i, cols,
+                # scans, sequential over the batch to bound the working
+                # set.  Each scan starts from the survivors' running sums
+                # and adds this round's blocks in order, as the kernel
+                # does, so the sums round exactly as the kernel's
+                int_tier = quantized and not is_pq
+
+                def one(args):
+                    idx_i, q_i, s_i, *rest = args
+                    qs_i = rest.pop(0) if int_tier else None
+                    s20 = rest[0][idx_i] if track_var else None
+                    if is_pq:
+                        return _scan_pulls(s_i[idx_i], Vq, q_i, idx_i, cols,
                                            sums2=s20, codebook=codebook)
-                    parts = jax.lax.map(one, (idx, Qb))        # (B, T, R)
-                elif quantized:
-                    def one(args):
-                        idx_i, Q8_i, qs_i = args
-                        s0 = jnp.zeros((rnd.n_arms, R), jnp.float32)
-                        s20 = jnp.zeros_like(s0) if track_var else None
-                        return _scan_pulls(s0, Vq, Q8_i, idx_i, cols,
+                    if int_tier:
+                        return _scan_pulls(s_i[idx_i], Vq, q_i, idx_i, cols,
                                            vscale, qs_i, sums2=s20,
                                            packed_int4=packed4)
-                    parts = jax.lax.map(one, (idx, Q8, qscale))  # (B, T, R)
-                else:
-                    def one(args):
-                        idx_i, Qb_i = args
-                        s0 = jnp.zeros((rnd.n_arms, R), jnp.float32)
-                        s20 = jnp.zeros_like(s0) if track_var else None
-                        return _scan_pulls(s0, V4, Qb_i, idx_i, cols,
-                                           sums2=s20)
-                    parts = jax.lax.map(one, (idx, Qb))        # (B, T, R)
+                    return _scan_pulls(s_i[idx_i], V4, q_i, idx_i, cols,
+                                       sums2=s20)
+                xs = ((idx, Q8 if int_tier else Qb, sums)
+                      + ((qscale,) if int_tier else ())
+                      + ((sums2,) if track_var else ()))
+                parts = jax.lax.map(one, xs)                  # (B, T, R)
                 if track_var:
                     parts, parts2 = parts
-                    sums2 = gate(sums2.at[brange, idx].add(parts2), sums2)
+                    sums2 = gate(sums2.at[brange, idx].set(parts2), sums2)
                 if adaptive:
-                    sums = gate(sums.at[brange, idx].add(parts), sums)
+                    sums = gate(sums.at[brange, idx].set(parts), sums)
                 else:
-                    sums = sums.at[brange, idx].add(parts)
+                    sums = sums.at[brange, idx].set(parts)
         t_prev = rnd.t_cum
         denom = jnp.float32(t_prev * C)
         means = jnp.take_along_axis(sums, idx[..., None], axis=1)

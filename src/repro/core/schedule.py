@@ -124,6 +124,14 @@ class FlatSchedule:
     happens after the step.  Everything here is host-side numpy computed at
     trace time — no traced values.
 
+    Round 1 starts from every tile, so its pulls need no survivor state:
+    they are left to the kernel's dense phase, which reads the first
+    ``t_dense`` permuted column blocks of all ``n_dense`` tiles once for
+    the whole batch.  The per-step arrays describe each query's own part:
+    round 1 shrinks to one no-pull step carrying its round-end flag, and
+    the later rounds follow.  `col_pos` lists every permutation position
+    the kernel reads: the dense phase's ``t_dense``, then one per step.
+
     Step layout per round: blocks outermost, survivor slots innermost, so a
     given arm tile accumulates its coordinate blocks in permutation order
     (the same order the `lax.scan` fallback uses, which is what makes
@@ -146,11 +154,31 @@ class FlatSchedule:
     n_keep: np.ndarray    # (S,) int32  survivors kept at the elimination
     t_final: int          # pulls per survivor entering the final top-K
     n_final: int          # survivor count entering the final top-K
+    t_dense: int          # round-1 column blocks pulled by the dense phase
+    n_dense: int          # tiles the dense phase covers (round 1's arms)
 
     @property
     def n_steps(self) -> int:
-        """Total kernel grid steps (pull + no-op elimination steps)."""
+        """Per-query kernel grid steps (pull + no-op elimination steps)."""
         return int(self.slot.shape[0])
+
+    @property
+    def dense_pulls(self) -> int:
+        """Pulls per query that the dense phase serves (round 1's)."""
+        return self.t_dense * self.n_dense
+
+    @property
+    def pull_steps(self) -> int:
+        """Pulls per query in all: the dense phase's plus the per-query
+        steps' (the schedule's sample complexity, plus any coverage)."""
+        return self.dense_pulls + int(self.is_pull.sum())
+
+    @property
+    def col_pos(self) -> np.ndarray:
+        """(t_dense + S,) int32 permutation positions the kernel reads:
+        the dense phase's columns in order, then ``bpos`` per step."""
+        return np.concatenate([np.arange(self.t_dense, dtype=np.int32),
+                               self.bpos]).astype(np.int32)
 
     def stacked(self) -> np.ndarray:
         """(S, 7) int32 view — handy for oracles and debugging."""
@@ -193,8 +221,11 @@ def flatten_schedule(sched: Schedule, *,
         is_end.append(end); t_cum.append(t); n_surv.append(T); n_keep.append(k)
 
     t_prev = 0
-    for r in sched.rounds:
-        if r.t_new == 0:
+    t_dense = n_dense = 0
+    for j, r in enumerate(sched.rounds):
+        if j == 0 and r.t_new > 0:    # pulled by the kernel's dense phase
+            t_dense, n_dense = r.t_cum, r.n_arms
+        if r.t_new == 0 or j == 0:
             emit(0, 0, 0, 1, r.t_cum, r.n_arms, r.n_keep)
         else:
             for p in range(t_prev, r.t_cum):
@@ -221,7 +252,7 @@ def flatten_schedule(sched: Schedule, *,
         t_cum=np.asarray(t_cum, np.int32),
         n_surv=np.asarray(n_surv, np.int32),
         n_keep=np.asarray(n_keep, np.int32),
-        t_final=t_final, n_final=n_final)
+        t_final=t_final, n_final=n_final, t_dense=t_dense, n_dense=n_dense)
 
 
 def cert_coeffs(sched: Schedule) -> np.ndarray:

@@ -7,13 +7,18 @@ Because the round structure is data-independent (`repro.core.schedule`), the
 *entire* multi-round pull program can be flattened host-side
 (`flatten_schedule`) and executed as a single grid (DESIGN.md §3):
 
-  * the (n_tiles, R) f32 accumulator stays VMEM-resident and the survivor
-    index set SMEM-resident across all rounds — neither round-trips to HBM;
-  * each grid step manually DMAs exactly one surviving (R, C) tile of V
-    from HBM (double-buffered: the next step's tile is prefetched while the
-    current tile-dot runs).  Only the bytes the bandit pulls ever cross
-    the memory bus, and the survivor indices live in SMEM, so the
-    "gather" costs no HBM traffic at all;
+  * round 1 starts from every tile, so it needs no survivor state: a
+    dense phase pulls its ``t_dense`` permuted column blocks once for the
+    whole batch, ``T_blk`` tiles of one column per step (about 1 MiB a
+    DMA), and adds every query's share to that query's round-1 sums;
+  * after round 1 the (n_tiles, R) f32 accumulator stays VMEM-resident
+    and the survivor index set SMEM-resident across all rounds — neither
+    round-trips to HBM;
+  * each later grid step manually DMAs exactly one surviving (R, C) tile
+    of V from HBM (double-buffered: the next step's tile is prefetched
+    while the current tile-dot runs).  Only the bytes the bandit pulls
+    ever cross the memory bus, and the survivor indices live in SMEM, so
+    the "gather" costs no HBM traffic at all;
   * at round boundaries the tile elimination runs *inside* the kernel: the
     survivors' masked tile-max means are written to SMEM in slot order and
     stably sorted descending there (a bottom-up merge sort — exactly
@@ -22,10 +27,27 @@ Because the round structure is data-independent (`repro.core.schedule`), the
   * the final top-K arms are extracted in-kernel and returned as (ids,
     scores) — dispatch count per query drops from O(rounds) to 1.
 
-The query axis rides in the grid, ``grid=(B, S)``: one launch serves a
-(B, N) decode batch, with per-query accumulator/survivor state
-re-initialized at each query's first step.  The single-query entry point is
-the same kernel at B = 1.
+The grid is flat, ``G1 + B * S`` steps, and one launch serves a (B, N)
+decode batch.  The first ``G1`` steps are the dense phase: with one column
+list for the batch (``cols (t_dense + S,)``, the decode path) each step
+scores all B queries, ``G1 = ceil(n_tiles / T_blk) * t_dense``; with a
+list per query (``cols (B, t_dense + S)``) each scores one query and
+``G1`` is B times that.  Query ``b``'s own steps follow, ``G1 + b * S``
+to ``G1 + (b + 1) * S - 1``: the first one re-initializes its survivor
+state, reads its round-1 sums back and ends round 1 (elimination, then
+certification when adaptive) without a pull; rounds 2 and after run as
+before.  VMEM holds one query's accumulator at a time, so the dense phase
+stages each finished tile group's ``(B, T_blk, R)`` sums (the first R of
+128 lanes) to an HBM buffer (an extra ``pl.ANY`` output the wrapper drops) and each query's first
+step DMAs its slice back.  A schedule with no rounds has no dense phase
+and its sums start at zero.  The single-query entry point is the same
+kernel at B = 1.
+
+The dense phase does the same arithmetic as a pull step, so results stay
+bitwise equal to the jnp fallbacks in interpret mode: per (query, tile,
+row) the fp32 parts are `f32_tile_dot` and int8/int4 parts the same exact
+integer dot dequantized with ``vscale * qscale``, added from 0.0 in
+permutation order; adaptive mode also sums ``part * part``.
 
 Mosaic memory rules shape the layout: scalars are only loaded and stored in
 SMEM, so every per-step scalar (survivor ids, sort keys, round cursor,
@@ -43,8 +65,10 @@ Scalar-prefetch operands (SMEM, 1-D):
   cert (2(r+1),)          adaptive only: per-round certification-radius
                           coefficients (a_l, b_l) from
                           `repro.core.schedule.cert_coeffs` (DESIGN.md §12)
-  cols (S,) or (B*S,)     column-block id pulled per step (perm[bpos]);
-                          (S,) when the batch shares one permutation
+  cols (t_dense+S,) or    column-block ids: round 1's ``t_dense`` dense
+       (B*(t_dense+S),)   columns, then one per step
+                          (``perm[flat.col_pos]``); the first form when
+                          the batch shares one permutation
   nvalid (1,)             rows >= nvalid are masked out of every ranking
                           (tile padding AND caller padding, e.g. a padded
                           vocab or a ragged shard — DESIGN.md §7); may be
@@ -106,6 +130,7 @@ narrow tiles.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +147,8 @@ __all__ = ["fused_cascade_pallas", "fused_cascade_batched_pallas"]
 _VMEM_CAP = 100 * 2**20
 _SMEM_CAP_WORDS = 240_000
 _ROW_CHUNK = 512        # rows per step of the chunked VMEM reductions
+_DENSE_DMA_BYTES = 2**20   # VMEM bytes of one dense-phase slab DMA
+_STAGE_ROWS = 2048         # staged rows a query reads back per DMA
 _BIG = 2**30            # "no index" sentinel of the arg-reductions
 
 
@@ -187,6 +214,33 @@ def _get_elem(buf, flat, R):
                              -jnp.inf))
 
 
+def _tile_dot_lanes(tiles, qcol):
+    """`f32_tile_dot` of T tiles, lane-dense: (T, R, C) x (1, C) -> (1, T R).
+
+    The same products and the same tree of halves, so the same bits: the
+    halves down to 128 lanes run across vregs as in `tree_sum`, and the
+    rest across the rows of the transposed (T R, 128) block instead of
+    within lanes, which costs the VPU far fewer shuffles.  ``C > 128``:
+    the products' own halving stays ahead of the transpose, where a
+    compiler that fuses a multiply into the add after it does so on both
+    paths alike.
+    """
+    T, R, C = tiles.shape
+    assert C > 128, C
+    x = tiles.astype(jnp.float32) * qcol.astype(jnp.float32)[None]
+    P = 1 << max(C - 1, 0).bit_length()
+    if P != C:     # `tree_sum` pads to a power of two with zeros
+        x = jnp.concatenate([x, jnp.zeros((T, R, P - C), x.dtype)], axis=-1)
+    while x.shape[-1] > 128:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    x = x.reshape(T * R, x.shape[-1]).T
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        x = x[:h] + x[h:]
+    return x
+
+
 def _sort_desc(key, val, key2, val2, T):
     """Stable descending sort of SMEM ``(key, val)[0:T]`` in place.
 
@@ -225,10 +279,16 @@ def _sort_desc(key, val, key2, val2, T):
     jax.lax.while_loop(lambda w: w < T, two_passes, jnp.int32(1))
 
 
+def _dense_chunk(R):
+    """Tiles whose rows fill the 128 lanes of a dense pass's sums."""
+    return max(1, 128 // R)
+
+
 def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                  qkind="none", adaptive=False, track_var=False, k_cert=1,
-                 n_rounds=0, shared_cols=True, ch=8, ch_f=8, q_dtype=None):
-    """Build the kernel body for a (B, S) grid.
+                 n_rounds=0, shared_cols=True, ch=8, ch_f=8, q_dtype=None,
+                 t_dense, T_blk, xch):
+    """Build the kernel body for the flat grid of ``G1 + B * S`` steps.
 
     ``qkind`` selects the pull arithmetic (DESIGN.md §10): 'none' (f32
     tile-dot), 'int8'/'int4' (the tensor-operand list grows by (vscale,
@@ -246,8 +306,19 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
     is the extraction width ``k_out``).  ``ch``/``ch_f`` are the row
     chunks of the (n_tiles, R) and (n_final, R) buffer reductions;
     ``q_dtype`` is the true dtype of a query block passed 32-bit wide.
+    ``t_dense`` round-1 columns run in the dense phase, ``T_blk`` tiles
+    a step, and each query reads its staged sums back ``xch`` rows a DMA;
+    ``S`` counts each query's own steps.
     """
     dims = (((1,), (1,)), ((), ()))        # (1, C) x (R, C) -> (1, R)
+    n_tblk = -(-n_tiles // T_blk) if t_dense else 0
+    T_last = n_tiles - (n_tblk - 1) * T_blk    # tiles of the last group
+    Bq = B if shared_cols else 1     # queries one dense step scores
+    per_group = n_tblk * t_dense     # dense steps per query group
+    G1 = (1 if shared_cols else B) * per_group
+    W = t_dense + S                  # columns per query in ``cols``
+    x_rows = _round_up(n_tiles, 8)   # staged rows read back per query
+    chunk = _dense_chunk(R)          # tiles one fp32 dense pass sums
 
     def kernel(code_ref, rmeta_ref, *more):
         if adaptive:
@@ -261,31 +332,80 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
         elif qkind == "pq":
             cb_ref, *rest = rest
         ids_ref, vals_ref, *rest = rest
-        rused_ref = None
+        rused_ref = stage = stage2 = None
         if adaptive:
             rused_ref, *rest = rest
+        if G1:
+            stage, *rest = rest
+            if track_var:
+                stage2, *rest = rest
         acc, *rest = rest
         acc2 = None
         if track_var:
             acc2, *rest = rest
         vbuf, surv, key2, val2, keys, cand, st, *rest = rest
         if adaptive:
-            minlb, bufM, bufU, bufL, sem = rest
+            minlb, bufM, bufU, bufL, *rest = rest
+        if G1:
+            dbuf, dacc, *rest = rest
+            dacc2 = None
+            if track_var:
+                dacc2, *rest = rest
+            xbuf, sem, dsem = rest
         else:
             (sem,) = rest
-        b, i = pl.program_id(0), pl.program_id(1)
+        g = pl.program_id(0)
+        in_q = g >= G1                       # a query's own step
+        gq = jnp.maximum(g - G1, 0)
+        b, i = jax.lax.div(gq, S), jax.lax.rem(gq, S)
         nv = nv_ref[0]
         code = code_ref[i]
         slot = code & SLOT_MASK
-        pull = (code & PULL_BIT) != 0
-        end = (code & END_BIT) != 0
+        pull = jnp.logical_and(in_q, (code & PULL_BIT) != 0)
+        end = jnp.logical_and(in_q, (code & END_BIT) != 0)
 
-        def col_at(bb, ii):
-            return cols_ref[ii] if shared_cols else cols_ref[bb * S + ii]
+        def col_at(bb, ii):      # column of query bb's own step ii
+            return (cols_ref[t_dense + ii] if shared_cols
+                    else cols_ref[bb * W + t_dense + ii])
 
         col = col_at(b, i)
         dslot = jax.lax.rem(i, 2)
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+
+        def q_block(qi, cl):
+            """(1, Cq) block ``cl`` of query ``qi``, at its true dtype."""
+            qcol = q_ref[qi, pl.ds(cl, 1), :]
+            if q_dtype is not None:      # narrow values held 32-bit wide
+                qcol = qcol.astype(q_dtype)
+            return qcol
+
+        def tile_part(tilebuf, qcol, cl, tile, qi):
+            """(1, R) partial products of one pulled (R, Cs) tile."""
+            if qkind == "pq":
+                # per-pull LUT of query-vs-codeword products + one-hot
+                # lookups per row — the SHARED `pq_tile_dot`, so the jnp
+                # fallbacks run literally the same f32 ops (bit-exact)
+                cb = cb_ref[pl.ds(cl, 1)][0]       # (S, n_codes, w)
+                return pq_tile_dot(tilebuf, qcol[0], cb)[None]
+            if qkind in ("int8", "int4"):
+                # int8 x int8 -> int32 on the MXU, then dequantize with the
+                # tile/block scale product.  The jnp fallback does the
+                # identical (exact) integer dot and the identical two float
+                # ops per entry, so the paths stay bit-exact.
+                if qkind == "int4":
+                    tilebuf = unpack_int4(tilebuf)
+                raw = jax.lax.dot_general(qcol, tilebuf, dims,
+                                          preferred_element_type=jnp.int32)
+                gi = tile // 128
+                lane128 = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+                vs = jnp.max(jnp.where(lane128 == tile - gi * 128,
+                                       vs_ref[cl, pl.ds(gi, 1), :],
+                                       -jnp.inf), axis=1, keepdims=True)
+                return raw.astype(jnp.float32) * (
+                    vs * qs_ref[qi * n_blocks + cl])
+            # f32 products + lane reduction — the SHARED `f32_tile_dot`,
+            # so the jnp fallbacks sum in the same order (bit-exact)
+            return f32_tile_dot(tilebuf, qcol)                  # (1, R)
 
         def masked_means(tile, denom):
             """(1, R) means of ``tile``'s rows; invalid rows -> -inf."""
@@ -293,16 +413,140 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
             valid = tile * R + lane < nv
             return jnp.where(valid, mu, -jnp.inf), mu, valid
 
-        @pl.when(i == 0)
+        if G1:
+            # -- the dense phase: round 1 for the whole batch, T_blk tiles
+            # of one column a step, each tile group's sums staged to HBM
+            def dense_at(gg):    # step -> (query group, tile group, column)
+                qg = jax.lax.div(gg, per_group)
+                rem = gg - qg * per_group
+                tb = jax.lax.div(rem, t_dense)
+                c = rem - tb * t_dense
+                cl = cols_ref[c] if shared_cols else cols_ref[qg * W + c]
+                return qg, tb, c, cl
+
+            def dense_dma(tb, cl, ds, wait):
+                def run(t0, n):
+                    dst = dbuf.at[ds] if n == T_blk else dbuf.at[
+                        ds, pl.ds(0, n)]
+                    cp = pltpu.make_async_copy(V_ref.at[pl.ds(t0, n), cl],
+                                               dst, dsem.at[ds])
+                    cp.wait() if wait else cp.start()
+                if T_last == T_blk:
+                    run(pl.multiple_of(tb * T_blk, 8), T_blk)
+                    return
+                if n_tblk > 1:    # the last group is short: its own size
+                    pl.when(tb < n_tblk - 1)(
+                        lambda: run(pl.multiple_of(tb * T_blk, 8), T_blk))
+                pl.when(tb == n_tblk - 1)(
+                    lambda: run((n_tblk - 1) * T_blk, T_last))
+
+            @pl.when(g == 0)
+            def _start_dense():
+                _, tb0, _, cl0 = dense_at(0)
+                dense_dma(tb0, cl0, 0, wait=False)
+
+            @pl.when(g < G1)
+            def _dense():
+                qg, tb, c, cl = dense_at(g)
+                ds = jax.lax.rem(g, 2)
+                dense_dma(tb, cl, ds, wait=True)
+
+                @pl.when(g + 1 < G1)
+                def _():
+                    _, ntb, _, ncl = dense_at(g + 1)
+                    dense_dma(ntb, ncl, 1 - ds, wait=False)
+
+                @pl.when(c == 0)
+                def _():   # sums start at 0.0, as the fallbacks' do
+                    dacc[...] = jnp.zeros(dacc.shape, jnp.float32)
+                    if track_var:
+                        dacc2[...] = jnp.zeros(dacc2.shape, jnp.float32)
+
+                n_t = jnp.where(tb == n_tblk - 1, T_last, T_blk)
+
+                lanes = pl.ds(0, R)    # the stage is 128 lanes wide
+
+                def add(j, rows, part):
+                    dacc[j, rows, lanes] = dacc[j, rows, lanes] + part
+                    if track_var:
+                        dacc2[j, rows, lanes] = (dacc2[j, rows, lanes]
+                                                 + part * part)
+
+                def one_query(j, _):
+                    qi = j if shared_cols else qg
+                    qcol = q_block(qi, cl)
+                    if qkind in ("int8", "int4"):
+                        def per_tile(t, _):  # the pull step's own arithmetic
+                            add(j, pl.ds(t, 1),
+                                tile_part(dbuf[ds, t], qcol, cl,
+                                          tb * T_blk + t, qi))
+                            return 0
+                        jax.lax.fori_loop(0, n_t, per_tile, 0)
+                    else:
+                        def per_chunk(k, _):  # `chunk` tiles a pass
+                            t0 = pl.multiple_of(k * chunk, chunk)
+                            slab = dbuf[ds, pl.ds(t0, chunk)]
+                            if qkind == "pq":
+                                cb = cb_ref[pl.ds(cl, 1)][0]
+                                add(j, pl.ds(t0, chunk),
+                                    pq_tile_dot(slab, qcol[0], cb))
+                            elif C > 128:
+                                sums = _tile_dot_lanes(slab, qcol)
+                                for t in range(chunk):   # (1, R) a tile
+                                    add(j, pl.ds(t0 + t, 1),
+                                        sums[:, t * R:(t + 1) * R])
+                            else:
+                                add(j, pl.ds(t0, chunk),
+                                    f32_tile_dot(slab, qcol))
+                            return 0
+                        jax.lax.fori_loop(0, (n_t + chunk - 1) // chunk,
+                                          per_chunk, 0)
+                    return 0
+                jax.lax.fori_loop(0, Bq, one_query, 0)
+
+                @pl.when(c == t_dense - 1)
+                def _stage():      # the group's round-1 sums, to HBM
+                    q0 = 0 if shared_cols else qg
+                    rows = pl.ds(pl.multiple_of(tb * T_blk, 8), T_blk)
+                    for src, dst in ((dacc, stage), (dacc2, stage2)):
+                        if src is not None:
+                            cp = pltpu.make_async_copy(
+                                src, dst.at[pl.ds(q0, Bq), rows], dsem.at[2])
+                            cp.start()
+                            cp.wait()
+
+        @pl.when(jnp.logical_and(in_q, i == 0))
         def _init():  # per-query state (re-entered at each b in the batch)
-            def zero(c, _):
-                row0 = pl.multiple_of(c * ch, ch)
-                acc[pl.ds(row0, ch), :] = jnp.zeros((ch, R), jnp.float32)
-                if track_var:
-                    acc2[pl.ds(row0, ch), :] = jnp.zeros((ch, R),
-                                                         jnp.float32)
-                return 0
-            jax.lax.fori_loop(0, acc.shape[0] // ch, zero, 0)
+            if G1:   # round 1's sums, staged by the dense phase
+                def fetch(k, _):
+                    # the last chunk may overlap the one before: it copies
+                    # the same values again
+                    r0 = pl.multiple_of(jnp.minimum(k * xch, x_rows - xch),
+                                        8)
+                    for src, dst in ((stage, acc), (stage2, acc2)):
+                        if src is not None:
+                            cp = pltpu.make_async_copy(
+                                src.at[b, pl.ds(r0, xch)], xbuf, dsem.at[2])
+                            cp.start()
+                            cp.wait()
+
+                            def put(r, _):
+                                rr = pl.multiple_of(r * 8, 8)
+                                dst[pl.ds(r0 + rr, 8), :] = xbuf[
+                                    pl.ds(rr, 8), pl.ds(0, R)]
+                                return 0
+                            jax.lax.fori_loop(0, xch // 8, put, 0)
+                    return 0
+                jax.lax.fori_loop(0, -(-x_rows // xch), fetch, 0)
+            else:
+                def zero(c, _):
+                    row0 = pl.multiple_of(c * ch, ch)
+                    acc[pl.ds(row0, ch), :] = jnp.zeros((ch, R), jnp.float32)
+                    if track_var:
+                        acc2[pl.ds(row0, ch), :] = jnp.zeros((ch, R),
+                                                             jnp.float32)
+                    return 0
+                jax.lax.fori_loop(0, acc.shape[0] // ch, zero, 0)
 
             def ident(j, _):
                 surv[j] = j
@@ -314,10 +558,15 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                 st[2] = t_final            # t_stop (actual pulls)
                 rused_ref[b] = n_rounds
 
-        @pl.when(jnp.logical_and(jnp.logical_and(b == 0, i == 0), pull))
+        @pl.when(g == max(G1 - 1, 0))
         def _start_first():  # every later step is prefetched by the previous
-            pltpu.make_async_copy(V_ref.at[surv[slot], col], vbuf.at[0],
-                                  sem.at[0]).start()
+            ncode = code_ref[0]
+
+            @pl.when((ncode & PULL_BIT) != 0)
+            def _():   # query 0 starts on identity slots
+                pltpu.make_async_copy(
+                    V_ref.at[ncode & SLOT_MASK, col_at(0, 0)], vbuf.at[0],
+                    sem.at[0]).start()
 
         # a certified (inactive) query's remaining pulls are masked no-ops:
         # no DMA wait, no accumulate — and _warm below starts no DMA for it
@@ -328,36 +577,7 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
             tile = surv[slot]
             pltpu.make_async_copy(V_ref.at[tile, col], vbuf.at[dslot],
                                   sem.at[dslot]).wait()
-            qcol = q_ref[0, pl.ds(col, 1), :]                  # (1, Cq)
-            if q_dtype is not None:      # narrow values held 32-bit wide
-                qcol = qcol.astype(q_dtype)
-            tilebuf = vbuf[dslot]                              # (R, Cs)
-            if qkind == "pq":
-                # per-pull LUT of query-vs-codeword products + one-hot
-                # lookups per row — the SHARED `pq_tile_dot`, so the jnp
-                # fallbacks run literally the same f32 ops (bit-exact)
-                cb = cb_ref[pl.ds(col, 1)][0]      # (S, n_codes, w)
-                part = pq_tile_dot(tilebuf, qcol[0], cb)[None]     # (1, R)
-            elif qkind in ("int8", "int4"):
-                # int8 x int8 -> int32 on the MXU, then dequantize with the
-                # tile/block scale product.  The jnp fallback does the
-                # identical (exact) integer dot and the identical two float
-                # ops per entry, so the paths stay bit-exact.
-                if qkind == "int4":
-                    tilebuf = unpack_int4(tilebuf)
-                raw = jax.lax.dot_general(qcol, tilebuf, dims,
-                                          preferred_element_type=jnp.int32)
-                g = tile // 128
-                lane128 = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-                vs = jnp.max(jnp.where(lane128 == tile - g * 128,
-                                       vs_ref[col, pl.ds(g, 1), :],
-                                       -jnp.inf), axis=1, keepdims=True)
-                part = raw.astype(jnp.float32) * (
-                    vs * qs_ref[b * n_blocks + col])
-            else:
-                # f32 products + lane reduction — the SHARED `f32_tile_dot`,
-                # so the jnp fallbacks sum in the same order (bit-exact)
-                part = f32_tile_dot(tilebuf, qcol)                 # (1, R)
+            part = tile_part(vbuf[dslot], q_block(b, col), col, tile, b)
             acc[pl.ds(tile, 1), :] = acc[pl.ds(tile, 1), :] + part
             if track_var:
                 acc2[pl.ds(tile, 1), :] = (acc2[pl.ds(tile, 1), :]
@@ -428,7 +648,7 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
             st[0] = r + 1
 
         # prefetch the next step's tile (post-elimination survivor indices)
-        @pl.when(i < S - 1)
+        @pl.when(jnp.logical_and(in_q, i < S - 1))
         def _warm():
             ncode = code_ref[i + 1]
             npull = (ncode & PULL_BIT) != 0
@@ -441,7 +661,8 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                     V_ref.at[surv[ncode & SLOT_MASK], col_at(b, i + 1)],
                     vbuf.at[1 - dslot], sem.at[1 - dslot]).start()
 
-        @pl.when(jnp.logical_and(i == S - 1, b < B - 1))
+        @pl.when(jnp.logical_and(jnp.logical_and(in_q, i == S - 1),
+                                 b < B - 1))
         def _warm_next_query():  # next query restarts on identity slots
             ncode = code_ref[0]
 
@@ -451,7 +672,7 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                     V_ref.at[ncode & SLOT_MASK, col_at(b + 1, 0)],
                     vbuf.at[0], sem.at[0]).start()
 
-        @pl.when(i == S - 1)
+        @pl.when(jnp.logical_and(in_q, i == S - 1))
         def _finalize():
             if adaptive:   # normalize by the query's ACTUAL pull count
                 denom_f = (jnp.maximum(st[2], 1) * C).astype(jnp.float32)
@@ -512,8 +733,8 @@ def _resolve_qkind(Cs, vscale, qscale, codebook, packed_int4):
 
 
 def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
-           n_final, k_out, n_valid, vscale, qscale, codebook, packed_int4,
-           cert, k_cert, track_var, interpret):
+           n_final, t_dense, k_out, n_valid, vscale, qscale, codebook,
+           packed_int4, cert, k_cert, track_var, interpret):
     """Shared body of the single-query and batched entry points."""
     n_tiles, n_blocks, R, Cs = V4.shape
     qkind, C = _resolve_qkind(Cs, vscale, qscale, codebook, packed_int4)
@@ -530,14 +751,16 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
     S = slotcode.shape[0]
     cols = jnp.asarray(cols, jnp.int32)
     shared_cols = cols.ndim == 1
+    W = t_dense + S                  # dense columns, then one per step
+    if cols.shape not in ((W,), (B, W)):
+        raise ValueError(
+            f"cols must be (t_dense + S,) or (B, t_dense + S) with t_dense "
+            f"= {t_dense}, S = {S} steps and B = {B}; got {cols.shape} "
+            f"(`FlatSchedule.col_pos` gives the positions)")
     n_rounds = rounds_meta.shape[0] - 1
     ch = min(_ROW_CHUNK, _round_up(n_tiles, 8))
     n_rows = _round_up(n_tiles, ch)
     ch_f = _round_up(n_final, 8)
-    in_specs = [
-        pl.BlockSpec(memory_space=pl.ANY),                  # V4: manual DMA
-        pl.BlockSpec((1,) + Qb.shape[1:], lambda b, i, *_: (b, 0, 0)),
-    ]
     q_dtype = None
     if Qb.dtype.itemsize < 4:
         # a dynamic one-row load of packed (int8 / bf16) sublanes is
@@ -546,6 +769,10 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
         q_dtype = Qb.dtype
         Qb = Qb.astype(jnp.int32 if jnp.issubdtype(q_dtype, jnp.integer)
                        else jnp.float32)
+    in_specs = [
+        pl.BlockSpec(memory_space=pl.ANY),                  # V4: manual DMA
+        pl.BlockSpec(memory_space=pltpu.VMEM),              # all queries
+    ]
     operands = [V4, Qb]
     if qkind in ("int8", "int4"):
         # vscale transposed and lane-dense: (n_blocks, n_tiles/128, 128)
@@ -559,56 +786,104 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
     elif qkind == "pq":
         in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))  # codebook
         operands.append(jnp.asarray(codebook, jnp.float32))
-    smem_out = pl.BlockSpec(memory_space=pltpu.SMEM)
-    out_shape = [jax.ShapeDtypeStruct((B * K,), jnp.int32),
-                 jax.ShapeDtypeStruct((B * K,), jnp.float32)]
-    if adaptive:
-        out_shape.append(jax.ShapeDtypeStruct((B,), jnp.int32))
     scalars = [slotcode.astype(jnp.int32),
                rounds_meta.astype(jnp.int32).reshape(-1)]
     if adaptive:
         scalars.append(jnp.asarray(cert, jnp.float32).reshape(-1))
     scalars += [cols.reshape(-1), jnp.asarray(n_valid, jnp.int32).reshape(1)]
-    scratch = [pltpu.VMEM((n_rows, R), jnp.float32)]   # accumulator
-    if track_var:
-        # running M2 accumulator feeding the 'bernstein' radii
-        scratch.append(pltpu.VMEM((n_rows, R), jnp.float32))
-    scratch += [
-        pltpu.VMEM((2, R, Cs), V4.dtype),     # double-buffered tile DMA
-        pltpu.SMEM((n_tiles,), jnp.int32),    # survivor tile ids
-        pltpu.SMEM((n_tiles,), jnp.float32),  # merge-sort ping-pong keys
-        pltpu.SMEM((n_tiles,), jnp.int32),    # merge-sort ping-pong ids
-        pltpu.SMEM((n_tiles,), jnp.float32),  # elimination sort keys
-        pltpu.VMEM((ch_f, R), jnp.float32),   # final extraction rows
-        pltpu.SMEM((3,), jnp.int32),          # round cursor, active, t_stop
-    ]
-    if adaptive:
-        scratch += [pltpu.SMEM((1,), jnp.float32)]        # min lower bound
-        scratch += [pltpu.VMEM((n_rows, R), jnp.float32)] * 3  # M / U / L
-    scratch.append(pltpu.SemaphoreType.DMA((2,)))
-    params = None
-    if not interpret:
+    n_sums = 2 if track_var else 1
+    Bq = B if shared_cols else 1
+    # HBM refuses a slice of a minor dim narrower than 128 lanes, so the
+    # staged sums sit in the first R of L lanes
+    L = _round_up(R, 128)
+
+    def layout(T_blk, xch):
+        """(out_shape, scratch, dense VMEM bytes) with ``T_blk`` tiles per
+        dense step and ``xch`` staged rows per read-back DMA."""
+        out_shape = [jax.ShapeDtypeStruct((B * K,), jnp.int32),
+                     jax.ShapeDtypeStruct((B * K,), jnp.float32)]
+        if adaptive:
+            out_shape.append(jax.ShapeDtypeStruct((B,), jnp.int32))
+        if t_dense:   # round-1 sums staged in HBM; dropped by the wrapper
+            rows = _round_up(n_tiles, T_blk)
+            out_shape += [jax.ShapeDtypeStruct((B, rows, L), jnp.float32)
+                          ] * n_sums
+        # accumulator, plus the running M2 feeding 'bernstein' radii
+        scratch = [pltpu.VMEM((n_rows, R), jnp.float32)] * n_sums
+        scratch += [
+            pltpu.VMEM((2, R, Cs), V4.dtype),     # double-buffered tile DMA
+            pltpu.SMEM((n_tiles,), jnp.int32),    # survivor tile ids
+            pltpu.SMEM((n_tiles,), jnp.float32),  # merge-sort ping-pong keys
+            pltpu.SMEM((n_tiles,), jnp.int32),    # merge-sort ping-pong ids
+            pltpu.SMEM((n_tiles,), jnp.float32),  # elimination sort keys
+            pltpu.VMEM((ch_f, R), jnp.float32),   # final extraction rows
+            pltpu.SMEM((3,), jnp.int32),          # round cursor, active, t_stop
+        ]
+        if adaptive:
+            scratch += [pltpu.SMEM((1,), jnp.float32)]        # min lower bound
+            scratch += [pltpu.VMEM((n_rows, R), jnp.float32)] * 3  # M / U / L
+        dense = []
+        if t_dense:
+            dense.append(pltpu.VMEM((2, T_blk, R, Cs), V4.dtype))  # slabs
+            dense += [pltpu.VMEM((Bq, T_blk, L), jnp.float32)] * n_sums
+            dense.append(pltpu.VMEM((xch, L), jnp.float32))  # read-back
+        scratch += dense
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+        if t_dense:   # the two slab slots + the staging transfers
+            scratch.append(pltpu.SemaphoreType.DMA((3,)))
+        return out_shape, scratch, sum(_vmem_bytes(d.shape, d.dtype)
+                                       for d in dense)
+
+    def vmem_of(scratch):
         vmem = sum(_vmem_bytes(s.shape, s.dtype) for s in scratch
                    if getattr(s, "memory_space", None) == pltpu.VMEM)
-        vmem += 2 * _vmem_bytes(Qb.shape[1:], Qb.dtype)
-        vmem += sum(_vmem_bytes(x.shape, x.dtype) for x in operands[2:]
-                    if x.ndim > 1)
+        vmem += _vmem_bytes(Qb.shape, Qb.dtype)
+        return vmem + sum(_vmem_bytes(x.shape, x.dtype)
+                          for x in operands[2:] if x.ndim > 1)
+
+    # dense steps move about _DENSE_DMA_BYTES each, whole passes of 8-row
+    # aligned tiles; when VMEM is short the slabs and the read-back shrink
+    unit = math.lcm(8, _dense_chunk(R))
+    tile_bytes = _vmem_bytes((R, Cs), V4.dtype)
+    T_blk = min(max(unit, _DENSE_DMA_BYTES // tile_bytes // unit * unit),
+                _round_up(n_tiles, unit))
+    xch = min(_round_up(n_tiles, 8), _STAGE_ROWS)
+    out_shape, scratch, dense_vmem = layout(T_blk, xch)
+    while (not interpret and vmem_of(scratch) > _VMEM_CAP
+           and max(T_blk // unit, xch // 8) > 1):
+        T_blk = max(unit, T_blk // 2 // unit * unit)
+        xch = max(8, xch // 2 // 8 * 8)
+        out_shape, scratch, dense_vmem = layout(T_blk, xch)
+    G1 = (1 if shared_cols else B) * -(-n_tiles // T_blk) * t_dense
+    params = None
+    if not interpret:
+        # the dense phase's buffers, the whole query block beyond a
+        # double-buffered query and the dense columns ride above the caps:
+        # a shape is refused by the same rule as without a dense phase
+        extra = dense_vmem + max(0, _vmem_bytes(Qb.shape, Qb.dtype)
+                                 - 2 * _vmem_bytes(Qb.shape[1:], Qb.dtype))
+        vmem = vmem_of(scratch)
         smem = sum(x.size for x in scalars) + 4 * n_tiles + B * (2 * K + 1)
         if qkind in ("int8", "int4"):
             smem += qscale.size
-        if vmem > _VMEM_CAP or smem > _SMEM_CAP_WORDS:
+        if (vmem - extra > _VMEM_CAP
+                or smem - cols.size // W * t_dense > _SMEM_CAP_WORDS):
             raise ValueError(
                 f"fused cascade does not fit one TensorCore: VMEM "
                 f"{vmem} B (cap {_VMEM_CAP}), SMEM {smem} words (cap "
                 f"{_SMEM_CAP_WORDS}) for n_tiles={n_tiles}, S={S}, B={B}")
         params = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=min(_VMEM_CAP, vmem + 8 * 2**20))
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(_VMEM_CAP + extra, vmem + 8 * 2**20))
+    out_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * (
+        3 if adaptive else 2)
+    out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * (
+        len(out_shape) - len(out_specs))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, S),
+        grid=(G1 + B * S,),
         in_specs=in_specs,
-        out_specs=tuple([smem_out] * len(out_shape)),
+        out_specs=tuple(out_specs),
         scratch_shapes=scratch,
     )
     kernel = _make_kernel(R=R, C=C, K=K, n_tiles=n_tiles, t_final=t_final,
@@ -616,7 +891,8 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
                           qkind=qkind, adaptive=adaptive,
                           track_var=track_var, k_cert=k_cert,
                           n_rounds=n_rounds, shared_cols=shared_cols,
-                          ch=ch, ch_f=ch_f, q_dtype=q_dtype)
+                          ch=ch, ch_f=ch_f, q_dtype=q_dtype,
+                          t_dense=t_dense, T_blk=T_blk, xch=xch)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -629,13 +905,13 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
     return (ids, vals, out[2]) if adaptive else (ids, vals)
 
 
-_STATIC = ("n_arms", "K", "t_final", "n_final", "k_out", "k_cert",
-           "track_var", "packed_int4", "interpret")
+_STATIC = ("n_arms", "K", "t_final", "n_final", "t_dense", "k_out",
+           "k_cert", "track_var", "packed_int4", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
-                         K: int, t_final: int, n_final: int,
+                         K: int, t_final: int, n_final: int, t_dense: int,
                          k_out: int = None, n_valid=None,
                          vscale=None, qscale=None, codebook=None,
                          packed_int4: bool = False, cert=None,
@@ -649,8 +925,9 @@ def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
     S) with ``codebook``.
     qb:  (n_blocks, C) blocked query (VMEM-resident) — f32 on the fp32
     AND pq paths, int8 on the int8/int4 (W4A8) paths.
-    slotcode/rounds_meta: see `FlatSchedule.packed`; cols (S,): the
-    column-block id of every step (``perm[flat.bpos]``).
+    slotcode/rounds_meta: see `FlatSchedule.packed`; cols (t_dense + S,):
+    the dense phase's round-1 column-block ids, then one per step
+    (``perm[flat.col_pos]``); ``t_dense`` is `FlatSchedule.t_dense`.
     k_out: number of final candidates extracted in-kernel (default K).
     Shard-local callers ask for k_out > K so the K winners come back with a
     threshold candidate for bound-gap computation; K only sizes the
@@ -676,7 +953,8 @@ def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
     how many elimination rounds actually pulled before certification.
     """
     out = _fused(V4, qb[None], slotcode, rounds_meta, cols, n_arms=n_arms,
-                 K=K, t_final=t_final, n_final=n_final, k_out=k_out,
+                 K=K, t_final=t_final, n_final=n_final, t_dense=t_dense,
+                 k_out=k_out,
                  n_valid=n_valid, vscale=vscale,
                  qscale=None if qscale is None else qscale[None],
                  codebook=codebook, packed_int4=packed_int4, cert=cert,
@@ -687,19 +965,21 @@ def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_cascade_batched_pallas(V4, Qb, slotcode, rounds_meta, cols, *,
                                  n_arms: int, K: int, t_final: int,
-                                 n_final: int, k_out: int = None,
+                                 n_final: int, t_dense: int,
+                                 k_out: int = None,
                                  n_valid=None, vscale=None, qscale=None,
                                  codebook=None, packed_int4: bool = False,
                                  cert=None, k_cert: int = 1,
                                  track_var: bool = False,
                                  interpret: bool = False):
-    """Batched fused cascade: the query axis rides in the grid.
+    """Batched fused cascade: one flat grid serves the whole batch.
 
-    Qb: (B, n_blocks, C) blocked queries; cols: (B, S) per-query pull
-    columns, or (S,) when the whole batch shares one permutation (the
-    decode path — it keeps SMEM at one word per step).  One dispatch
-    serves the whole decode batch; per-query state is re-initialized at
-    each query's first grid step.  ``k_out`` (default K) widens the
+    Qb: (B, n_blocks, C) blocked queries; cols: (B, t_dense + S) per-query
+    pull columns, or (t_dense + S,) when the whole batch shares one
+    permutation (the decode path — it keeps SMEM at one word per step and
+    lets each dense round-1 step score every query).  One dispatch serves
+    the whole decode batch; per-query state is re-initialized at each
+    query's first grid step.  ``k_out`` (default K) widens the
     in-kernel final extraction and ``n_valid`` (default ``n_arms``, may be
     traced) masks caller-padding rows exactly as in
     `fused_cascade_pallas`.  For int8 operands pass ``vscale`` (n_tiles,
@@ -715,7 +995,8 @@ def fused_cascade_batched_pallas(V4, Qb, slotcode, rounds_meta, cols, *,
     ``cert`` also ``rounds_used (B,) int32``.
     """
     return _fused(V4, Qb, slotcode, rounds_meta, cols, n_arms=n_arms, K=K,
-                  t_final=t_final, n_final=n_final, k_out=k_out,
+                  t_final=t_final, n_final=n_final, t_dense=t_dense,
+                  k_out=k_out,
                   n_valid=n_valid, vscale=vscale, qscale=qscale,
                   codebook=codebook, packed_int4=packed_int4, cert=cert,
                   k_cert=k_cert, track_var=track_var, interpret=interpret)

@@ -67,16 +67,18 @@ def gather_block_dot(V4, idx, cols, qsel):
 
 
 def fused_cascade(V4, qb, slotcode, rounds_meta, cols, *, n_arms, K,
-                  t_final, n_final, k_out=None, n_valid=None,
+                  t_final, n_final, t_dense, k_out=None, n_valid=None,
                   vscale=None, qscale=None, codebook=None,
                   packed_int4=False, cert=None, k_cert=1,
                   track_var=False):
     """Whole-cascade single dispatch: see `repro.kernels.fused_cascade`.
 
-    Beyond the schedule operands: ``k_out`` (default K) widens the
-    in-kernel final extraction so shard-local callers get extra threshold
-    candidates (it never changes the elimination schedule; must satisfy
-    ``K <= k_out <= n_final * tile``); ``n_valid`` (default ``n_arms``,
+    ``t_dense`` is the flat schedule's dense round-1 column count
+    (``cols`` holds those columns first).  Beyond the schedule operands:
+    ``k_out`` (default K) widens the in-kernel final extraction so
+    shard-local callers get extra threshold candidates (it never changes
+    the elimination schedule; must satisfy ``K <= k_out <= n_final *
+    tile``); ``n_valid`` (default ``n_arms``,
     may be a traced scalar) masks rows >= n_valid out of every tile-max
     and extraction so caller padding can never win (DESIGN.md §7);
     ``vscale``/``qscale`` are the int8/int4 dequantization scales of the
@@ -91,7 +93,8 @@ def fused_cascade(V4, qb, slotcode, rounds_meta, cols, *, n_arms, K,
     """
     return fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols,
                                 n_arms=n_arms, K=K, t_final=t_final,
-                                n_final=n_final, k_out=k_out,
+                                n_final=n_final, t_dense=t_dense,
+                                k_out=k_out,
                                 n_valid=n_valid, vscale=vscale,
                                 qscale=qscale, codebook=codebook,
                                 packed_int4=packed_int4, cert=cert,
@@ -100,7 +103,8 @@ def fused_cascade(V4, qb, slotcode, rounds_meta, cols, *, n_arms, K,
 
 
 def fused_cascade_batched(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K,
-                          t_final, n_final, k_out=None, n_valid=None,
+                          t_final, n_final, t_dense, k_out=None,
+                          n_valid=None,
                           vscale=None, qscale=None, codebook=None,
                           packed_int4=False, cert=None, k_cert=1,
                           track_var=False):
@@ -113,7 +117,8 @@ def fused_cascade_batched(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K,
     """
     return fused_cascade_batched_pallas(V4, Qb, slotcode, rounds_meta, cols,
                                         n_arms=n_arms, K=K, t_final=t_final,
-                                        n_final=n_final, k_out=k_out,
+                                        n_final=n_final, t_dense=t_dense,
+                                        k_out=k_out,
                                         n_valid=n_valid, vscale=vscale,
                                         qscale=qscale, codebook=codebook,
                                         packed_int4=packed_int4, cert=cert,

@@ -41,7 +41,8 @@ def fused_cascade_ref(V4, qb, flat, cols, *, n_arms: int, K: int,
     shares no code with either the kernel or the `lax.scan` fallback.
 
     V4: (n_tiles, n_blocks, R, C); qb: (n_blocks, C); flat: FlatSchedule;
-    cols: (S,) column-block id per step (i.e. perm[flat.bpos]).
+    cols: (t_dense + S,) column-block ids: the dense phase's, then one per
+    step (i.e. perm[flat.col_pos]).
     With ``vscale (n_tiles, n_blocks)`` / ``qscale (n_blocks,)`` the
     operands are int8 and each pull is an exact integer dot dequantized by
     the scalar scale product (the quantized path, DESIGN.md §10);
@@ -113,25 +114,30 @@ def fused_cascade_ref(V4, qb, flat, cols, *, n_arms: int, K: int,
         a = int(np.argmax(buf == m))
         return a, np.float32(m)
 
+    def pull(tile, col):
+        if is_pq:
+            cb = codebook[col]                          # (S, n_codes, w)
+            lut = (qb[col].reshape(S, 1, w) * cb).sum(-1)
+            codes = V4[tile, col]                       # (R, S) uint8
+            part = np.stack([
+                lut[np.arange(S), codes[r]].sum()
+                for r in range(R)]).astype(np.float32)
+        elif quantized:
+            raw = V4[tile, col] @ qb[col]               # exact int32
+            s = np.float32(vscale[tile, col]) * np.float32(qscale[col])
+            part = raw.astype(np.float32) * s
+        else:
+            part = V4[tile, col] @ qb[col]
+        acc[tile] = acc[tile] + part
+        acc2[tile] = acc2[tile] + part * part
+
+    # round 1's dense phase: every tile, columns in permutation order
+    for j in range(flat.t_dense):
+        for tile in range(flat.n_dense):
+            pull(tile, int(cols[j]))
     for i in range(flat.n_steps):
         if flat.is_pull[i] and (not adaptive or active):
-            tile = surv[flat.slot[i]]
-            col = int(cols[i])
-            if is_pq:
-                cb = codebook[col]                          # (S, n_codes, w)
-                lut = (qb[col].reshape(S, 1, w) * cb).sum(-1)
-                codes = V4[tile, col]                       # (R, S) uint8
-                part = np.stack([
-                    lut[np.arange(S), codes[r]].sum()
-                    for r in range(R)]).astype(np.float32)
-            elif quantized:
-                raw = V4[tile, col] @ qb[col]               # exact int32
-                s = np.float32(vscale[tile, col]) * np.float32(qscale[col])
-                part = raw.astype(np.float32) * s
-            else:
-                part = V4[tile, col] @ qb[col]
-            acc[tile] = acc[tile] + part
-            acc2[tile] = acc2[tile] + part * part
+            pull(surv[flat.slot[i]], int(cols[flat.t_dense + i]))
         if flat.is_end[i]:
             T, keep = int(flat.n_surv[i]), int(flat.n_keep[i])
             denom = np.float32(int(flat.t_cum[i]) * C)

@@ -329,9 +329,15 @@ class CascadeExecutor:
         self._c_compiles = self.metrics.counter(
             "cascade_compiles_total",
             "XLA compiles run inside a dispatch's launch.", keys)
-        self._c_dispatch.seed(**lbl)
-        self._c_recal.seed(**lbl)
-        self._c_compiles.seed(**lbl)
+        self._c_pulls = self.metrics.counter(
+            "cascade_pulls_total",
+            "Lane-pulls of the plan's flat schedule, per dispatch.", keys)
+        self._c_dense_pulls = self.metrics.counter(
+            "cascade_dense_pulls_total",
+            "Lane-pulls the kernel's dense round-1 phase serves.", keys)
+        for c in (self._c_dispatch, self._c_recal, self._c_compiles,
+                  self._c_pulls, self._c_dense_pulls):
+            c.seed(**lbl)
         self._seen_version = (0 if self.store is None
                               else self.store.version)
         self._table_np = None   # host copy, materialized only for recall
@@ -368,7 +374,8 @@ class CascadeExecutor:
         the calibrated bound — the only events that change the schedule
         (and therefore recompile) on the dynamic path.
         """
-        from repro.core.boundedme_jax import bounded_me_decode, make_plan
+        from repro.core.boundedme_jax import (bounded_me_decode,
+                                              fused_flat_schedule, make_plan)
 
         self._plan_value_range = float(value_range)
         mesh, model_axis = self.mesh, self._model_axis
@@ -448,6 +455,12 @@ class CascadeExecutor:
                     return (out if adaptive else (*out, None))
 
                 donate = 1
+
+        # per-dispatch pull counts of the plan (one shard's under a mesh)
+        flat = fused_flat_schedule(self.plan, final_exact=True,
+                                   adaptive=adaptive)
+        self._lane_pulls = self.lanes * flat.pull_steps
+        self._lane_dense_pulls = self.lanes * flat.dense_pulls
 
         # donate the query buffer: steady-state flushes recycle the same
         # (lanes, N) device allocation (no-op on backends without
@@ -535,6 +548,8 @@ class CascadeExecutor:
                 jax.block_until_ready(scores)
         dt = time.perf_counter() - t0
         self._c_dispatch.inc(**self._mlabels)
+        self._c_pulls.inc(self._lane_pulls, **self._mlabels)
+        self._c_dense_pulls.inc(self._lane_dense_pulls, **self._mlabels)
         self._h_dispatch.observe(dt * 1e3, **self._mlabels)
         with phase("executor.d2h", didx=didx):
             return (np.asarray(ids), np.asarray(scores),

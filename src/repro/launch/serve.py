@@ -590,7 +590,8 @@ def _run_decode_demo(args) -> None:
         path = ("fused pallas_call, dispatches_per_decode_batch=1"
                 if on_tpu() else "jnp scan fallback (non-TPU backend)")
         print(f"[serve] fused cascade: rounds={len(plan.schedule.rounds)} "
-              f"grid_steps={flat.n_steps} "
+              f"dense_round1_pulls={flat.dense_pulls} "
+              f"steps_per_query={flat.n_steps} "
               f"precision={plan.precision} "
               f"pull_speedup={plan.schedule.speedup:.2f}x path={path}")
 

@@ -4,9 +4,11 @@ Interpret mode accepts layouts Mosaic refuses (scalar VMEM stores,
 blocks off the (8, 128) tiling, VMEM/SMEM overflow), so these tests lower
 the real kernel for a *described* v5e chip — no chip needed — on
 `jax.ShapeDtypeStruct` operands at the serving shape: the 153,600-row
-padded vocabulary x 1,024 table, K = 4, B = 8 lanes (and the single-query
-entry point).  The topology is described inside a fixture, never at import:
-only one process may load the TPU compiler library at a time.
+padded vocabulary x 1,024 table, K = 4, B = 8 lanes sharing one column
+list or with one each (and the single-query entry point); round 1 runs
+in the kernel's dense phase.  The topology is described inside a
+fixture, never at import: only one process may load the TPU compiler
+library at a time.
 """
 
 import os
@@ -47,13 +49,16 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compile(one_chip, *, precision, adaptive, batched, track_var=False):
-    cfg = get_config("qwen1.5-0.5b")
+def _compile(one_chip, *, precision, adaptive, batched, track_var=False,
+             per_query_cols=False, arch="qwen1.5-0.5b", pull_mode="row"):
+    cfg = get_config(arch)
     n, d = cfg.padded_vocab, cfg.d_model
     plan = make_plan(n, d, K=K, eps=0.1, delta=0.1, value_range=0.2,
-                     block=min(512, d), precision=precision)
+                     block=min(512, d), precision=precision,
+                     pull_mode=pull_mode)
     flat = flatten_schedule(plan.schedule, final_coverage=(
         precision == "fp32" and not adaptive))
+    assert flat.t_dense > 0          # round 1 runs in the dense phase
     slotcode, rmeta = flat.packed()
     R, C, nt, nb = plan.tile, plan.block, plan.n_tiles, plan.n_blocks
 
@@ -64,7 +69,8 @@ def _compile(one_chip, *, precision, adaptive, batched, track_var=False):
     q_shape = (LANES, nb, C) if batched else (nb, C)
     args = [sds((nt, nb, R, C), vdt), sds(q_shape, vdt),
             sds(slotcode.shape, jnp.int32), sds(rmeta.shape, jnp.int32),
-            sds(flat.bpos.shape, jnp.int32)]
+            sds(((LANES,) if per_query_cols else ()) + flat.col_pos.shape,
+                jnp.int32)]
     extra = {}
     if precision == "int8":
         extra["vscale"] = sds((nt, nb), jnp.float32)
@@ -75,7 +81,8 @@ def _compile(one_chip, *, precision, adaptive, batched, track_var=False):
 
     def run(*a, **e):
         return fn(*a, **e, n_arms=plan.n, K=K, t_final=flat.t_final,
-                  n_final=flat.n_final, n_valid=cfg.vocab, k_cert=K,
+                  n_final=flat.n_final, t_dense=flat.t_dense,
+                  n_valid=cfg.vocab, k_cert=K,
                   track_var=track_var, interpret=False)
 
     return jax.jit(run).lower(*args, **extra).compile()
@@ -92,12 +99,34 @@ def test_fused_cascade_compiles_for_v5e(one_chip, batched, precision, mode):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("mode", ["fixed", "adaptive", "bernstein"])
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+def test_per_query_columns_compile_for_v5e(one_chip, precision, mode):
+    """A column list per query: each dense round-1 step scores one query,
+    B times the dense steps of the shared list."""
+    compiled = _compile(one_chip, precision=precision,
+                        adaptive=mode != "fixed", batched=True,
+                        track_var=mode == "bernstein", per_query_cols=True)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+def test_coord_mode_compiles_for_v5e(one_chip, precision):
+    """128-wide coordinate tiles at tinyllama-1.1b width (32,768 x 2,048):
+    the dense phase sums them with `f32_tile_dot` itself."""
+    compiled = _compile(one_chip, precision=precision, adaptive=False,
+                        batched=True, arch="tinyllama-1.1b",
+                        pull_mode="coord")
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("tier", ["int4", "pq"])
 def test_tiers_without_mosaic_lowering_refuse_loudly(tier):
     """int4 and pq have no Mosaic lowering: they must raise, never fall
     back to interpret mode or the jnp path."""
     V4 = jnp.zeros((2, 1, 8, 64 if tier == "int4" else 16), jnp.int8)
-    kw = dict(n_arms=16, K=1, t_final=1, n_final=1, interpret=False)
+    kw = dict(n_arms=16, K=1, t_final=1, n_final=1, t_dense=0,
+              interpret=False)
     if tier == "int4":
         kw.update(vscale=jnp.ones((2, 1)), qscale=jnp.ones((1,)),
                   packed_int4=True)

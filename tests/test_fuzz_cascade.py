@@ -56,7 +56,7 @@ def _oracle_decode(V, Q, key, plan, *, k_out, n_valid, adaptive):
     Qb = np.asarray(Qp).reshape(B, plan.n_blocks, C)
     perm = np.asarray(jax.random.permutation(key, plan.n_blocks))
     flat = flatten_schedule(plan.schedule, final_coverage=False)
-    cols = perm[flat.bpos]
+    cols = perm[flat.col_pos]
     scale = np.float32((plan.n_blocks * C) / plan.N)
     cert = cert_coeffs(plan.schedule) if adaptive else None
     vscale = qscale = codebook = None

@@ -99,7 +99,7 @@ def _fused_setup(n, N, K, tile, block, eps=0.2, seed=0, final_exact=False):
     qb = qp.reshape(plan.n_blocks, plan.block)
     perm = jax.random.permutation(jax.random.PRNGKey(seed), plan.n_blocks)
     flat = flatten_schedule(plan.schedule, final_coverage=final_exact)
-    cols = np.asarray(perm)[flat.bpos]
+    cols = np.asarray(perm)[flat.col_pos]
     return V, q, plan, V4, qb, flat, cols
 
 
@@ -122,7 +122,8 @@ class TestFusedCascade:
         ids_k, vals_k = fused_cascade_pallas(
             V4, qb, jnp.asarray(slotcode), jnp.asarray(rmeta),
             jnp.asarray(cols), n_arms=plan.n, K=plan.K,
-            t_final=flat.t_final, n_final=flat.n_final, interpret=True)
+            t_final=flat.t_final, n_final=flat.n_final,
+            t_dense=flat.t_dense, interpret=True)
         ids_o, vals_o = ref.fused_cascade_ref(V4, qb, flat, cols,
                                               n_arms=plan.n, K=plan.K)
         np.testing.assert_array_equal(np.asarray(ids_k), ids_o)
@@ -168,9 +169,9 @@ class TestFusedCascade:
             lambda k: jax.random.permutation(k, plan.n_blocks))(keys)
         flat = flatten_schedule(plan.schedule)
         slotcode, rmeta = flat.packed()
-        cols = jnp.take(perms, jnp.asarray(flat.bpos), axis=1)
+        cols = jnp.take(perms, jnp.asarray(flat.col_pos), axis=1)
         kw = dict(n_arms=plan.n, K=plan.K, t_final=flat.t_final,
-                  n_final=flat.n_final, interpret=True)
+                  n_final=flat.n_final, t_dense=flat.t_dense, interpret=True)
         ids_b, vals_b = fused_cascade_batched_pallas(
             V4, Qb, jnp.asarray(slotcode), jnp.asarray(rmeta), cols, **kw)
         for b in range(B):
@@ -194,7 +195,8 @@ class TestFusedCascade:
         ids_k, vals_k = fused_cascade_pallas(
             V4, qb, jnp.asarray(slotcode), jnp.asarray(rmeta),
             jnp.asarray(cols), n_arms=plan.n, K=plan.K,
-            t_final=flat.t_final, n_final=flat.n_final, interpret=True)
+            t_final=flat.t_final, n_final=flat.n_final,
+            t_dense=flat.t_dense, interpret=True)
         ids_o, vals_o = ref.fused_cascade_ref(V4, qb, flat, cols,
                                               n_arms=plan.n, K=plan.K)
         np.testing.assert_array_equal(np.asarray(ids_k), ids_o)
@@ -207,3 +209,187 @@ def test_ops_wrappers_dispatch_interpret_on_cpu():
                                jnp.zeros((1,), jnp.int32),
                                jnp.ones((1, 128)))
     assert out.shape == (1, 8)
+
+
+def _grid_steps(fn, *args):
+    """Grid size of the one `pallas_call` that ``fn(*args)`` traces."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for v in eqn.params.values():
+                sub = getattr(v, "jaxpr", None)
+                sub = getattr(sub, "jaxpr", sub)
+                if sub is not None and (hit := find(sub)) is not None:
+                    return hit
+        return None
+
+    grid = find(jax.make_jaxpr(fn)(*args).jaxpr).params["grid_mapping"].grid
+    assert len(grid) == 1
+    return grid[0]
+
+
+def _dense_case(bound="hoeffding", precision="fp32", n=800, N=4096,
+                block=512, B=3, eps=0.7, seed=0):
+    """A table whose 100 tiles (800 rows) make two dense tile groups of
+    64 at fp32 and block 512, the second one short."""
+    from repro.core.boundedme_jax import make_plan
+
+    rng = np.random.default_rng(seed)
+    V = rng.normal(size=(n, N)).astype(np.float32)
+    Q = rng.normal(size=(B, N)).astype(np.float32)
+    plan = make_plan(n, N, K=2, eps=eps, delta=0.1, value_range=8.0,
+                     block=block, bound=bound, precision=precision,
+                     quant_err=0.05 if precision == "pq" else None)
+    return V, Q, plan
+
+
+def _assert_same(a, b, what):
+    for x, y, name in zip(a, b, ("ids", "scores", "rounds_used")):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f"{name} {what}")
+
+
+def dense_parity(layout, B, mode, precision="fp32", n=800, N=4096,
+                 block=512, eps=0.7):
+    """Kernel (dense round 1) vs both jnp fallbacks, bit for bit."""
+    from repro.core.boundedme_jax import bounded_me_batched, \
+        bounded_me_decode
+
+    adaptive = mode != "fixed"
+    bound = "bernstein" if mode == "bernstein" else "hoeffding"
+    V, Q, plan = _dense_case(bound, precision, n=n, N=N, block=block, B=B,
+                             eps=eps)
+    assert plan.schedule.rounds[0].t_cum < plan.n_blocks   # later pulls
+    key = jax.random.PRNGKey(B)
+    if layout == "shared":   # the decode path: one permutation, (S,) cols
+        kw = dict(plan=plan, final_exact=False, k_out=plan.K,
+                  adaptive=adaptive)
+        for nv in (n - 23, n):                           # ragged, full
+            out_k = bounded_me_decode(V, Q, key, use_pallas=True,
+                                      n_valid=nv, **kw)
+            out_j = bounded_me_decode(V, Q, key, use_pallas=False,
+                                      n_valid=nv, **kw)
+            _assert_same(out_k, out_j, f"vs decode fallback, n_valid {nv}")
+        # the scan path with every query on the same key pulls the same
+        # columns in the same order
+        out_s = bounded_me_batched(V, Q, jnp.stack([key] * B), plan=plan,
+                                   final_exact=False, use_pallas=False,
+                                   adaptive=adaptive)
+        _assert_same(out_k, out_s, "vs scan path")
+    else:                    # a permutation per query, (B, S) cols
+        keys = jax.random.split(key, B)
+        kw = dict(plan=plan, final_exact=False, adaptive=adaptive)
+        out_k = bounded_me_batched(V, Q, keys, use_pallas=True, **kw)
+        out_s = bounded_me_batched(V, Q, keys, use_pallas=False, **kw)
+        _assert_same(out_k, out_s, "vs scan path")
+
+
+class TestDenseRoundOne:
+    """Round 1 pulled once for the batch, in multi-tile steps."""
+
+    @pytest.mark.parametrize("mode", ["fixed", "hoeffding", "bernstein"])
+    @pytest.mark.parametrize("B", [1, 3, 8])
+    @pytest.mark.parametrize("layout", ["shared", "per_query"])
+    def test_dense_phase_bitwise_vs_fallbacks(self, layout, B, mode):
+        dense_parity(layout, B, mode)
+
+    @pytest.mark.parametrize("layout", ["shared", "per_query"])
+    def test_grid_runs_round_one_once(self, layout):
+        """G1 + B (S - S1 + 1) steps: S1 is round 1's pull steps of the
+        per-query layout, and G1 its dense steps, 64 tiles (1 MiB) each."""
+        from repro.core.boundedme_jax import (_run_batched_fused,
+                                              _run_decode)
+
+        B = 3
+        V, Q, plan = _dense_case(B=B)
+        rounds = plan.schedule.rounds
+        S = sum(max(1, r.n_arms * r.t_new) for r in rounds)  # one a query
+        S1 = rounds[0].n_arms * rounds[0].t_new
+        t1 = rounds[0].t_cum
+        assert (plan.n_tiles, t1) == (100, 7) and S1 == 700
+        groups = 1 if layout == "shared" else B
+        G1 = groups * 2 * t1          # ceil(100 / 64) tile groups
+        key = jax.random.PRNGKey(0)
+        if layout == "shared":
+            steps = _grid_steps(
+                lambda V, Q, k: _run_decode(V, Q, k, plan.n, plan=plan,
+                                            final_exact=False,
+                                            use_pallas=True, k_out=plan.K),
+                V, Q, key)
+        else:
+            steps = _grid_steps(
+                lambda V, Q, k: _run_batched_fused(V, Q, k, plan=plan,
+                                                   final_exact=False),
+                V, Q, jax.random.split(key, B))
+        assert steps == G1 + B * (S - S1 + 1)
+
+    def test_no_rounds_has_no_dense_phase(self):
+        """K >= n_tiles: nothing to eliminate, sums start at zero."""
+        from repro.core.boundedme_jax import (_run_decode,
+                                              bounded_me_decode, make_plan)
+        from repro.core.schedule import flatten_schedule
+
+        rng = np.random.default_rng(1)
+        V = rng.normal(size=(16, 256)).astype(np.float32)
+        Q = rng.normal(size=(3, 256)).astype(np.float32)
+        plan = make_plan(16, 256, K=8, eps=0.5, delta=0.1, value_range=8.0,
+                         block=64)
+        assert not plan.schedule.rounds
+        assert flatten_schedule(plan.schedule).t_dense == 0
+        key = jax.random.PRNGKey(2)
+        assert _grid_steps(
+            lambda V, Q, k: _run_decode(V, Q, k, 16, plan=plan,
+                                        final_exact=False, use_pallas=True,
+                                        k_out=plan.K),
+            V, Q, key) == 3                  # one finalize step a query
+        kw = dict(plan=plan, final_exact=False, n_valid=13)
+        _assert_same(bounded_me_decode(V, Q, key, use_pallas=True, **kw),
+                     bounded_me_decode(V, Q, key, use_pallas=False, **kw),
+                     "no rounds")
+
+    def test_pull_shares_of_command_r_shard_plan(self):
+        """The counters' share, from the plan alone: Command-R's 64,000 x
+        8,192 shard pulls 16,000 of its 29,220 tile-blocks a query in round
+        1 (the flat schedule's 29,224 steps include four no-pull round
+        ends)."""
+        from repro.core.boundedme_jax import fused_flat_schedule, make_plan
+
+        plan = make_plan(64000, 8192, K=1, eps=0.1, delta=0.1,
+                         value_range=0.24, tile=8, block=512)
+        flat = fused_flat_schedule(plan, final_exact=True)
+        assert (flat.dense_pulls, flat.pull_steps) == (16_000, 29_220)
+        assert flat.n_steps == 29_224 - 16_000 + 1
+
+
+def test_cols_must_match_the_dense_layout():
+    """Columns built from ``bpos`` alone miss round 1's dense columns: the
+    kernel refuses them rather than drop round 1's pulls."""
+    from repro.kernels.fused_cascade import fused_cascade_pallas
+
+    _, _, plan, V4, qb, flat, cols = _fused_setup(512, 2048, 3, 8, 128)
+    assert flat.t_dense > 0
+    slotcode, rmeta = flat.packed()
+    with pytest.raises(ValueError, match="col_pos"):
+        fused_cascade_pallas(
+            V4, qb, jnp.asarray(slotcode), jnp.asarray(rmeta),
+            jnp.asarray(cols[flat.t_dense:]), n_arms=plan.n, K=plan.K,
+            t_final=flat.t_final, n_final=flat.n_final,
+            t_dense=flat.t_dense, interpret=True)
+
+
+@pytest.mark.parametrize("T,R,C", [(16, 8, 512), (16, 8, 384), (16, 8, 256),
+                                   (32, 4, 1024), (8, 16, 200), (3, 8, 512)])
+def test_lane_dense_tile_dot_matches_f32_tile_dot_bitwise(T, R, C):
+    """The dense phase's transposed reduction (for blocks wider than 128)
+    adds the same pairs as `f32_tile_dot`'s tree: the same bits."""
+    from repro.core.quantize import f32_tile_dot
+    from repro.kernels.fused_cascade import _tile_dot_lanes
+
+    rng = np.random.default_rng(T * C + R)
+    tiles = rng.normal(size=(T, R, C)).astype(np.float32)
+    qcol = rng.normal(size=(1, C)).astype(np.float32)
+    want = np.asarray(jax.jit(f32_tile_dot)(tiles, qcol))
+    got = np.asarray(jax.jit(_tile_dot_lanes)(tiles, qcol))
+    assert got.shape == (1, T * R)
+    np.testing.assert_array_equal(got.reshape(T, R), want)

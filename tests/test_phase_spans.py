@@ -175,6 +175,28 @@ def test_a_fresh_executor_counts_its_first_compile():
         [lbl for lbl, _ in dispatches.rows()]
 
 
+def test_the_executor_counts_the_plans_pulls_and_the_dense_share():
+    """Each dispatch adds lanes x the flat schedule's pulls, and lanes x
+    round 1's dense pulls, with the dispatch counter's labels."""
+    from repro.core.boundedme_jax import fused_flat_schedule
+
+    ex = CascadeExecutor(_table(), K=1, eps=0.1, delta=0.1, lanes=LANES)
+    flat = fused_flat_schedule(ex.plan, final_exact=True)
+    assert 0 < flat.dense_pulls < flat.pull_steps     # later rounds pull
+    pulls = ex.metrics.get("cascade_pulls_total")
+    dense = ex.metrics.get("cascade_dense_pulls_total")
+    assert (pulls.total(), dense.total()) == (0, 0)
+    Q = np.zeros((LANES, DIM), np.float32)
+    for k in range(2):
+        ex.dispatch(Q, jax.random.PRNGKey(k))
+    assert pulls.total() == 2 * LANES * flat.pull_steps
+    assert dense.total() == 2 * LANES * flat.dense_pulls
+    dispatches = ex.metrics.get("cascade_dispatches_total")
+    for c in (pulls, dense):
+        assert [lbl for lbl, _ in c.rows()] == \
+            [lbl for lbl, _ in dispatches.rows()]
+
+
 def test_a_warmed_runtime_compiles_nothing_at_any_occupancy():
     rt = ServeRuntime(_table(1), K=1, eps=0.1, delta=0.1, lanes=LANES,
                       batch_wait_ms=1e-3, cache_entries=0,

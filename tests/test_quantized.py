@@ -198,6 +198,19 @@ class TestBitExactness:
         np.testing.assert_array_equal(np.asarray(i_f), np.asarray(i_j))
         np.testing.assert_array_equal(np.asarray(s_f), np.asarray(s_j))
 
+    # round 1 in the kernel's dense phase, at shapes whose last tile group
+    # is short: 64 int8 tiles a step (800 rows), 128 nibble-packed int4
+    # tiles (1,100 rows), 256 pq code tiles (2,100 rows); eps 1.5 leaves
+    # blocks to later rounds under the widened quantized bounds
+    @pytest.mark.parametrize("mode", ["fixed", "hoeffding", "bernstein"])
+    @pytest.mark.parametrize("layout", ["shared", "per_query"])
+    @pytest.mark.parametrize("tier,n", [("int8", 800), ("int4", 1100),
+                                        ("pq", 2100)])
+    def test_dense_phase_tiers_bitwise(self, tier, n, layout, mode):
+        from test_kernels import dense_parity
+
+        dense_parity(layout, 3, mode, precision=tier, n=n, eps=1.5)
+
     def test_fused_matches_numpy_oracle(self):
         from repro.core.boundedme_jax import _pad_operands, _tile_major
         from repro.core.schedule import flatten_schedule
@@ -215,13 +228,14 @@ class TestBitExactness:
         q8, qscale = quantize_blocks(qb)
         perm = jax.random.permutation(jax.random.PRNGKey(5), plan.n_blocks)
         flat = flatten_schedule(plan.schedule)
-        cols = np.asarray(perm)[flat.bpos]
+        cols = np.asarray(perm)[flat.col_pos]
         slotcode, rmeta = flat.packed()
         ids_k, vals_k = fused_cascade_pallas(
             V8, q8, jnp.asarray(slotcode), jnp.asarray(rmeta),
             jnp.asarray(cols), n_arms=plan.n, K=plan.K,
             t_final=flat.t_final, n_final=flat.n_final,
-            vscale=vscale, qscale=qscale, interpret=True)
+            t_dense=flat.t_dense, vscale=vscale, qscale=qscale,
+            interpret=True)
         ids_o, vals_o = ref.fused_cascade_ref(
             V8, q8, flat, cols, n_arms=plan.n, K=plan.K,
             vscale=vscale, qscale=qscale)
@@ -276,7 +290,8 @@ class TestBitExactness:
                 jnp.zeros((1, 128), jnp.int8),
                 jnp.zeros((1,), jnp.int32), jnp.zeros((1, 3), jnp.int32),
                 jnp.zeros((1,), jnp.int32), n_arms=8, K=1, t_final=1,
-                n_final=1, vscale=jnp.ones((1, 1)), interpret=True)
+                n_final=1, t_dense=0, vscale=jnp.ones((1, 1)),
+                interpret=True)
 
 
 class TestRecallRegression:

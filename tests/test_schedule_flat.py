@@ -18,8 +18,19 @@ def test_flatten_invariants(n, N, K, eps):
     flat = flatten_schedule(sched)
     # one end flag per round, in order
     assert int(flat.is_end.sum()) == len(sched.rounds)
-    # pull steps count = total sample complexity of the schedule
-    assert int(flat.is_pull.sum()) == sched.total_pulls
+    # pull steps count = total sample complexity of the schedule, round 1
+    # served by the dense phase: every arm, its first t_cum blocks
+    assert flat.pull_steps == sched.total_pulls
+    first = sched.rounds[0] if sched.rounds else None
+    assert flat.t_dense == (first.t_cum if first else 0)
+    assert flat.n_dense == (first.n_arms if first else 0)
+    assert flat.dense_pulls == (first.n_arms * first.t_new if first else 0)
+    # the per-query part opens with round 1's no-pull end step
+    if first:
+        assert (flat.is_pull[0], flat.is_end[0]) == (0, 1)
+    # the kernel reads the dense columns in order, then one per step
+    np.testing.assert_array_equal(
+        flat.col_pos, np.r_[np.arange(flat.t_dense), flat.bpos])
     # slots stay inside the round's survivor count
     assert (flat.slot < flat.n_surv).all()
     # block positions stay inside the reward list
@@ -40,7 +51,9 @@ def test_flatten_saturated_round_emits_noop_end_step():
     assert any(r.t_new == 0 for r in sched.rounds)
     flat = flatten_schedule(sched)
     noop_ends = (flat.is_pull == 0) & (flat.is_end == 1)
-    assert noop_ends.sum() == sum(r.t_new == 0 for r in sched.rounds)
+    # round 1's end step pulls nothing either: the dense phase pulled it
+    assert noop_ends.sum() == 1 + sum(r.t_new == 0
+                                      for r in sched.rounds[1:])
 
 
 def test_flatten_final_coverage_completes_to_N():
