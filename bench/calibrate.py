@@ -7,10 +7,13 @@ In one process, for each seed: one run of the cell as the benchmark makes
 it (`bench.harness.run`), whose compared numbers are the program's
 readings.  For each control seed, the same queries answered instead by
 
-* ``control``: the reference one precision step down (fp32 at
-  ``Precision.HIGH`` as three explicit bf16 passes,
-  `bench.reference.control_answers`), and ``control_xla_high``, the same
-  step as XLA's own ``Precision.HIGH``;
+* ``control``: the reference one precision step below how the
+  configuration stores its table (``table_dtype``, `bench.reference.CONTROL`):
+  over fp32, fp32 at ``Precision.HIGH`` as three explicit bf16 passes;
+  over bf16, one bf16 pass with the queries rounded to bf16
+  (`bench.reference.control_answers`);
+* ``control_xla_high``: XLA's own ``Precision.HIGH``, over fp32 the same
+  step as ``control``;
 * ``fault_shard``: the exact reference over the first shard's rows only,
   "the exchange between chips left out" (cells on several chips);
 * ``fault_altered``: the exact answers with each returned row moved to
@@ -55,7 +58,9 @@ def reference_readings(spec: dict, seed: int, count: int,
     ex_ids, ex_sc, _ = reference.exact_answers(
         table, nv, Q, np.zeros((count, K), np.int32), K)
     out = {}
-    answers = {"control": reference.control_answers(table, nv, Q, K),
+    step = reference.CONTROL[loadgen.table_dtype(cfg)]
+    answers = {"control": reference.control_answers(table, nv, Q, K,
+                                                    precision=step),
                "control_xla_high": reference.control_answers(
                    table, nv, Q, K, precision="xla_high")}
     if chips > 1:

@@ -305,6 +305,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(log_dir, ignore_errors=True)
     ctx = {"trace": summary, "window": window, "queued_ms": queued_ms,
            "plan": plan, "final_coverage": final_coverage,
+           "table_itemsize": table.dtype.itemsize,
            "device_kind": devs[0].device_kind}
 
     # ---- the check: every answer of the window against the reference

@@ -3,11 +3,13 @@
 Everything a run sends is a pure function of ``--seed`` and the data files
 (`bench/configs/<config>.json`, `bench/traffic/<traffic>.json`):
 
-* the item table: ``rows x d`` fp32 drawn as the repo's ``init_params``
-  draws an embedding (standard normal times ``init_std``), truncated at
-  ``trunc_sigmas`` so that the configuration's stated value range
+* the item table: ``rows x d`` drawn as the repo's ``init_params``
+  draws an embedding (fp32 standard normal times ``init_std``), truncated
+  at ``trunc_sigmas`` so that the configuration's stated value range
   ``2 * qmax_hint * trunc_sigmas * init_std`` bounds every product a
-  query can make with it;
+  query can make with it.  It is stored as the configuration's
+  ``table_dtype`` says (`table_dtype`): fp32 as drawn, or the draw
+  rounded to bf16 as a checkpoint's cast rounds it;
 * the queries: coordinates uniform in ``[-qmax_hint, qmax_hint]`` that lean
   towards one target row each, so that the target's exact mean product
   stands ``z`` standard deviations above the other rows' (``z`` drawn per
@@ -53,10 +55,40 @@ def value_range(cfg: dict) -> float:
     return 2.0 * cfg["qmax_hint"] * cfg["trunc_sigmas"] * cfg["init_std"]
 
 
-def make_table(cfg: dict, seed: int, sharding=None):
-    """The (rows, d) fp32 item table on the device, in one jitted call.
+#: how a configuration's ``table_dtype`` may store the table
+TABLE_DTYPES = ("float32", "bfloat16")
 
-    With ``sharding`` the table is created already laid out by it.
+
+def table_dtype(cfg: dict) -> str:
+    """The configuration's ``table_dtype``: how the table is stored.
+
+    It states storage only (``precision`` states the pulls' arithmetic);
+    a file without the key stores fp32.
+    """
+    name = cfg.get("table_dtype", "float32")
+    if name not in TABLE_DTYPES:
+        raise ValueError(f"table_dtype {name!r} in configuration "
+                         f"{cfg.get('name')!r}: use one of {TABLE_DTYPES}")
+    return name
+
+
+def bf16_cap(x: float) -> float:
+    """The largest bf16 value not above ``x`` (``x`` > 0)."""
+    import ml_dtypes
+
+    b = np.asarray(x, np.float32).astype(ml_dtypes.bfloat16)
+    if float(b) > x:
+        b = np.nextafter(b, np.zeros_like(b))
+    return float(b)
+
+
+def make_table(cfg: dict, seed: int, sharding=None):
+    """The (rows, d) item table on the device, in one jitted call.
+
+    A bf16 table is the fp32 draw rounded to nearest-even, then clipped
+    to the largest bf16 value not above ``trunc_sigmas * init_std``, so
+    that `value_range` still bounds every product.  With ``sharding`` the
+    table is created already laid out by it.
     """
     import jax
     import jax.numpy as jnp
@@ -64,10 +96,16 @@ def make_table(cfg: dict, seed: int, sharding=None):
     rows, d = cfg["rows"], cfg["d"]
     lim = float(cfg["trunc_sigmas"])
     std = float(cfg["init_std"])
+    bf16 = table_dtype(cfg) == "bfloat16"
+    cap = bf16_cap(lim * std) if bf16 else None
 
     def draw(key):
         z = jax.random.normal(key, (rows, d), jnp.float32)
-        return jnp.clip(z, -lim, lim) * jnp.float32(std)
+        v = jnp.clip(z, -lim, lim) * jnp.float32(std)
+        if bf16:
+            c = jnp.bfloat16(cap)
+            v = jnp.clip(v.astype(jnp.bfloat16), -c, c)
+        return v
 
     fn = jax.jit(draw, out_shardings=sharding)
     return fn(jax.random.PRNGKey(seed_words(seed, TABLE)))
@@ -84,8 +122,8 @@ def make_queries(table, cfg: dict, mix: dict, seed: int, count: int
     """``count`` queries (host fp32) and their target rows, in one call.
 
     Query i is ``clip(u_i + beta_i * v_t / init_std, +-qmax)`` with
-    ``u_i`` uniform in the box, ``v_t`` the target row and ``beta_i``
-    from `target_beta` at ``z_i ~ U(target_z)``.
+    ``u_i`` uniform in the box, ``v_t`` the target row as stored, widened
+    to fp32, and ``beta_i`` from `target_beta` at ``z_i ~ U(target_z)``.
     """
     import jax
     import jax.numpy as jnp
@@ -101,7 +139,8 @@ def make_queries(table, cfg: dict, mix: dict, seed: int, count: int
     def build(V, key, tgt, beta):
         u = jax.random.uniform(key, (tgt.shape[0], d), jnp.float32,
                                -qmax, qmax)
-        lean = V[tgt] * (beta[:, None] / jnp.float32(cfg["init_std"]))
+        lean = V[tgt].astype(jnp.float32) * (beta[:, None]
+                                             / jnp.float32(cfg["init_std"]))
         return jnp.clip(u + lean, -qmax, qmax)
 
     key = jax.random.PRNGKey(seed_words(seed, QUERIES))

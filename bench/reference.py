@@ -6,13 +6,26 @@ queries over a table regenerated from the seed (`bench.loadgen.make_table`):
 it imports nothing of the program and reads nothing the program made.
 Scores are mean products ``(q . v) / d``, the unit the program returns.
 
+A table stored in bf16 (``table_dtype``) is widened to fp32 before any
+product.  The widening is exact, so the reference scores the stored
+values with exact fp32 products.  Over a bf16 table a single bf16 pass
+(queries rounded to bf16) fails the check's ``score_rel_err`` limit of
+2e-6; a computation that splits the query into two or three bf16 parts
+(``high``, ``xla_high``) does not, since a bf16 row has no low part to
+drop: the limit tells fp32 products of the stored rows from one bf16
+pass only.
+
 The control is the same top-K in the program's place, computed one step
-below the configuration's precision: fp32 products as ``Precision.HIGH``
-makes them, three bf16 passes.  They are written out as three bf16 dots,
-each behind an optimization barrier, so that it computes alike on a CPU
-(whose backend ignores ``Precision.HIGH``) and on a TPU (whose compiler
-would otherwise fold the passes into fewer); ``xla_high`` is XLA's own
-``Precision.HIGH``, read beside it on the chip.
+below what the configuration states.  Over an fp32 table that is fp32
+products as ``Precision.HIGH`` makes them, three bf16 passes.  They are
+written out as three bf16 dots, each behind an optimization barrier, so
+that it computes alike on a CPU (whose backend ignores
+``Precision.HIGH``) and on a TPU (whose compiler would otherwise fold the
+passes into fewer); ``xla_high`` is XLA's own ``Precision.HIGH``, read
+beside it on the chip.  Over a bf16 table, whose rows split into no
+second bf16 pass, the step below is ``bf16``: one bf16 pass with the
+queries rounded to bf16, what a plain 16-bit matmul computes.  `CONTROL`
+maps each ``table_dtype`` to its control.
 """
 
 from __future__ import annotations
@@ -21,11 +34,19 @@ import numpy as np
 
 BLOCK = 256     # queries per reference block
 
+#: the control of each ``table_dtype``: the step below how it is stored
+CONTROL = {"float32": "high", "bfloat16": "bf16"}
+
 
 def _scores(V, Q, precision: str):
     import jax
     import jax.numpy as jnp
 
+    if precision == "bf16":
+        s = jnp.dot(Q.astype(jnp.bfloat16), V.astype(jnp.bfloat16).T,
+                    preferred_element_type=jnp.float32)
+        return s / jnp.float32(V.shape[1])
+    V = V.astype(jnp.float32)           # exact from bf16
     if precision == "highest":
         s = jnp.dot(Q, V.T, precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
@@ -72,7 +93,8 @@ def exact_answers(table, n_valid: int, Q: np.ndarray, ids: np.ndarray,
     def block(V, Qb, idb):
         s = _scores(V[:n_valid], Qb, "highest")
         top, pos = jax.lax.top_k(s, K)
-        return pos, top, V[jnp.clip(idb, 0, n_valid - 1)]
+        return pos, top, V[jnp.clip(idb, 0, n_valid - 1)].astype(
+            jnp.float32)
 
     tops, vals, got = [], [], []
     for i in range(0, len(Q), BLOCK):
@@ -95,8 +117,9 @@ def control_answers(table, n_valid: int, Q: np.ndarray, K: int,
                     precision: str = "high", rows=None):
     """Top-K ids and scores of ``Q``: the control at ``Precision.HIGH``.
 
-    ``precision`` is ``"high"`` (three explicit bf16 passes) or
-    ``"xla_high"`` (XLA's own ``Precision.HIGH``).
+    ``precision`` is ``"high"`` (three explicit bf16 passes),
+    ``"xla_high"`` (XLA's own ``Precision.HIGH``) or ``"bf16"`` (one
+    bf16 pass, the control of a bf16 table).
 
     ``precision="highest"`` with ``rows`` (start, stop), a slice of the
     table, is the reference with "the exchange between chips left out":

@@ -3,7 +3,9 @@
 `least_bytes` counts, once per dispatch, the pull steps of the schedule
 the executor's plan runs (every tile's pulls in every round, plus the
 final-coverage pulls that make the fp32 scores exact) times the bytes of
-one ``tile x block`` slab at the sampling precision.  Every query of the
+one ``tile x block`` slab as the table is stored: the unquantized tier
+at the table's own bytes per element (4 for fp32, 2 for bf16), a
+quantized tier at its width.  Every query of the
 batch needs at least those tiles, so any implementation of the dispatch
 reads at least this much, however it shares pulls across the batch; and
 no schedule pulls a (tile, block) cell twice, so the count never exceeds
@@ -32,9 +34,13 @@ def peaks(device_kind: str) -> dict:
 
 
 def slab_bytes(tile: int, block: int, precision: str,
-               pq_subdims: int = 8) -> float:
-    """Bytes of one ``tile x block`` table slab as the kernel reads it."""
-    per_row = {"fp32": 4.0 * block, "int8": 1.0 * block,
+               pq_subdims: int = 8, table_itemsize: int = 4) -> float:
+    """Bytes of one ``tile x block`` table slab as the kernel reads it.
+
+    ``table_itemsize`` is the stored table's bytes per element, which
+    the unquantized tier (``fp32``) reads as it is.
+    """
+    per_row = {"fp32": float(table_itemsize) * block, "int8": 1.0 * block,
                "int4": 0.5 * block,
                "pq": float(block // pq_subdims)}[precision]
     return tile * per_row
@@ -54,14 +60,17 @@ def pull_steps(rounds, n_blocks: int, final_coverage: bool) -> int:
     return steps
 
 
-def least_bytes(plan, *, final_coverage: bool) -> float:
+def least_bytes(plan, *, final_coverage: bool,
+                table_itemsize: int = 4) -> float:
     """Least HBM bytes of one dispatch of ``plan`` on one chip."""
     return pull_steps(plan.schedule.rounds, plan.n_blocks,
                       final_coverage) * slab_bytes(
-        plan.tile, plan.block, plan.precision, plan.pq_subdims)
+        plan.tile, plan.block, plan.precision, plan.pq_subdims,
+        table_itemsize)
 
 
-def table_scan_bytes(plan) -> float:
+def table_scan_bytes(plan, table_itemsize: int = 4) -> float:
     """Bytes of one scan of the (padded) table at the plan's precision."""
     return plan.n_tiles * plan.n_blocks * slab_bytes(
-        plan.tile, plan.block, plan.precision, plan.pq_subdims)
+        plan.tile, plan.block, plan.precision, plan.pq_subdims,
+        table_itemsize)

@@ -39,11 +39,36 @@ def test_count_of_the_cmdr_shard():
     assert roofline.least_bytes(plan, final_coverage=True) == 29220 * 16384
 
 
-@pytest.mark.parametrize("precision,per_elem", [("fp32", 4), ("int8", 1),
-                                                ("int4", 0.5)])
-def test_narrow_tiers_are_priced_at_their_width(precision, per_elem):
-    assert roofline.slab_bytes(8, 512, precision) == 8 * 512 * per_elem
+@pytest.mark.parametrize("precision,itemsize,per_elem", [
+    ("fp32", 4, 4), ("int8", 4, 1), ("int4", 4, 0.5),
+    ("fp32", 2, 2),                 # a bf16 table: half the fp32 bytes
+    ("int8", 2, 1)])                # a quantized tier keeps its width
+def test_narrow_tiers_are_priced_at_their_width(precision, itemsize,
+                                                per_elem):
+    assert roofline.slab_bytes(8, 512, precision,
+                               table_itemsize=itemsize) == 8 * 512 * per_elem
     assert roofline.slab_bytes(8, 512, "pq", pq_subdims=8) == 8 * 64
+    plan = _plan(64000, 8192, 1, 0.24, precision)
+    steps = roofline.pull_steps(plan.schedule.rounds, plan.n_blocks, True)
+    assert roofline.least_bytes(plan, final_coverage=True,
+                                table_itemsize=itemsize) == \
+        steps * 8 * 512 * per_elem
+    assert roofline.table_scan_bytes(plan, itemsize) == \
+        plan.n_tiles * plan.n_blocks * 8 * 512 * per_elem
+
+
+def test_the_roofline_reader_prices_the_table_as_stored():
+    from bench import harness
+
+    read = harness._reader("fused_cascade_roofline")
+    plan = _plan(64000, 8192, 1, 0.24)
+    ctx = {"trace": {"kernel_ops": 10, "kernel_s": 1.5375},
+           "window": {"dispatches": 10}, "plan": plan,
+           "final_coverage": True, "device_kind": "TPU v5 lite"}
+    fp32 = read(dict(ctx, table_itemsize=4))
+    # 29,220 pulls of 16 KiB at 819 GB/s over 153.75 ms a dispatch
+    assert fp32 == pytest.approx(100 * 29220 * 16384 / 819e9 / 0.15375)
+    assert read(dict(ctx, table_itemsize=2)) == fp32 / 2
 
 
 def test_unknown_device_is_an_error():

@@ -56,6 +56,40 @@ def test_the_control_in_the_programs_place_is_not_correct(monkeypatch):
         out["checks"]["score_rel_err"]["limit"]
 
 
+@pytest.mark.parametrize("answer", ["exact", "bf16_pass"])
+def test_a_bf16_table_is_checked_against_its_stored_values(monkeypatch,
+                                                          answer):
+    # the exact fp32 top-K of the stored bf16 rows in the program's place
+    # is correct; one bf16 pass, the step below, is not
+    import ml_dtypes
+
+    from bench import reference
+
+    seen = []
+
+    def replace(ex, Q, ids, scores):
+        seen.append(ex.table.dtype)
+        K = ids.shape[1]
+        if answer == "exact":
+            top, sc, _ = reference.exact_answers(
+                ex.table, ex._nv_static, Q, np.zeros((len(Q), K), np.int32),
+                K)
+            return top, sc
+        return reference.control_answers(
+            ex.table, ex._nv_static, Q, K,
+            precision=reference.CONTROL["bfloat16"])
+
+    _break_dispatch(monkeypatch, replace)
+    out = run_tiny(tiny_spec(table_dtype="bfloat16"))
+    assert seen and all(d == ml_dtypes.bfloat16 for d in seen)
+    err = out["checks"]["score_rel_err"]
+    if answer == "exact":
+        assert out["correct"], out["checks"]
+    else:
+        assert not out["correct"]
+        assert err["value"] > err["limit"]
+
+
 @pytest.mark.parametrize("part", ["ids", "scores"])
 def test_an_answer_altered_where_it_is_produced_is_not_correct(
         monkeypatch, part):

@@ -42,14 +42,61 @@ def test_queries_and_table_are_a_function_of_the_seed():
     assert not np.array_equal(q1, q3)
 
 
-def test_table_and_queries_obey_the_stated_value_range():
-    spec = tiny_spec()
+@pytest.mark.parametrize("table_dtype", [None, "bfloat16"])
+def test_table_and_queries_obey_the_stated_value_range(table_dtype):
+    spec = tiny_spec(table_dtype=table_dtype)
     cfg, mix = spec["config"], spec["mix"]
-    V = np.asarray(loadgen.make_table(cfg, 3))
+    V = np.asarray(loadgen.make_table(cfg, 3), np.float64)
     Q, _ = loadgen.make_queries(V, cfg, mix, 3, 64)
     assert np.abs(Q).max() <= cfg["qmax_hint"]
     bound = loadgen.value_range(cfg) / 2
-    assert np.abs(V).max() * np.abs(Q).max() <= bound + 1e-9
+    assert np.abs(V).max() * np.abs(Q).max() <= bound
+    assert np.max(np.abs(Q @ V.T)) / cfg["d"] <= bound
+
+
+def _draw(cfg, seed):
+    """The fp32 draw as the formula states it: normal, clipped, times std."""
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.PRNGKey(loadgen.seed_words(seed, loadgen.TABLE))
+    z = jax.random.normal(key, (cfg["rows"], cfg["d"]), jnp.float32)
+    lim = float(cfg["trunc_sigmas"])
+    return np.asarray(jnp.clip(z, -lim, lim) * jnp.float32(cfg["init_std"]))
+
+
+def test_an_fp32_table_is_the_draw_bit_for_bit():
+    cfg = tiny_spec()["config"]
+    assert "table_dtype" not in cfg
+    V = loadgen.make_table(cfg, 2 ** 33 + 5)
+    assert V.dtype == np.float32
+    np.testing.assert_array_equal(
+        np.asarray(V).view(np.uint32), _draw(cfg, 2 ** 33 + 5).view(np.uint32))
+
+
+def test_a_bf16_table_is_the_draw_rounded_to_nearest_even():
+    import ml_dtypes
+
+    cfg = tiny_spec(table_dtype="bfloat16")["config"]
+    V = loadgen.make_table(cfg, 2 ** 33 + 5)
+    assert V.dtype == ml_dtypes.bfloat16
+    cap = loadgen.bf16_cap(cfg["trunc_sigmas"] * cfg["init_std"])
+    up = np.asarray(cap, ml_dtypes.bfloat16)
+    up = float(np.nextafter(up, np.ones_like(up)))   # the next bf16 above
+    assert cap <= cfg["trunc_sigmas"] * cfg["init_std"] < up
+    rounded = _draw(cfg, 2 ** 33 + 5).astype(ml_dtypes.bfloat16)  # RNE
+    want = np.clip(rounded.astype(np.float32), -cap, cap)
+    np.testing.assert_array_equal(np.asarray(V, np.float32), want)
+    # the clip moves only the few draws that rounded past the cap
+    assert np.mean(np.asarray(V, np.float32) != rounded.astype(np.float32)) \
+        < 1e-4
+
+
+@pytest.mark.parametrize("name", ["float16", "fp32", "int8"])
+def test_an_unknown_table_dtype_is_refused(name):
+    cfg = tiny_spec(table_dtype=name)["config"]
+    with pytest.raises(ValueError, match="table_dtype"):
+        loadgen.make_table(cfg, 1)
 
 
 def test_targets_stand_out_as_the_mix_says():

@@ -2,15 +2,23 @@
 
 import json
 from pathlib import Path
+from typing import Optional
 
 BENCH = Path(__file__).resolve().parent.parent
 
 
-def tiny_spec(chips: int = 1, loop: str = "closed", K: int = 1) -> dict:
-    """The cmdr35b configuration cut to 1,000 x 1,536, with its limits."""
+def tiny_spec(chips: int = 1, loop: str = "closed", K: int = 1,
+              table_dtype: Optional[str] = None) -> dict:
+    """The cmdr35b configuration cut to 1,000 x 1,536, with its limits.
+
+    ``table_dtype``, when given, is set in the configuration (which
+    states none: fp32).
+    """
     cfg = json.loads((BENCH / "configs" / "cmdr35b-lmhead-shard4.json")
                      .read_text())
     cfg.update(rows=1024, n_valid=1000, d=1536, K=K)
+    if table_dtype is not None:
+        cfg["table_dtype"] = table_dtype
     name = "decode8" if loop == "closed" else "poisson"
     mix = json.loads((BENCH / "traffic" / f"{name}.json").read_text())
     mix.update(pool=256)
