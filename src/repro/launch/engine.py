@@ -77,9 +77,26 @@ class DispatchFailed(RuntimeError):
 #: a context variable, so that ``dispatch(Qbuf, key)`` keeps its signature
 #: for every caller and wrapper of it
 _DIDX = contextvars.ContextVar("repro_didx", default=-1)
+#: the lanes that the dispatch in flight fills, set beside ``_DIDX``;
+#: None (a direct ``dispatch`` call, a warm-up) counts every lane
+_FILLED = contextvars.ContextVar("repro_filled", default=None)
 
 
-def dispatch_with_retries(ex, Qbuf, key, *, didx: int, injector=None,
+def _slab_bytes(plan, table_itemsize: int) -> float:
+    """Bytes of one ``tile x block`` pull as the table is stored.
+
+    The unquantized tier reads the table at its own width (4 bytes an
+    element for fp32, 2 for bf16); a quantized tier reads its codes
+    (int8 1 byte, int4 half of one, pq one byte per ``pq_subdims``).
+    """
+    per_row = {"fp32": float(table_itemsize) * plan.block,
+               "int8": 1.0 * plan.block, "int4": 0.5 * plan.block,
+               "pq": float(plan.block // plan.pq_subdims)}[plan.precision]
+    return plan.tile * per_row
+
+
+def dispatch_with_retries(ex, Qbuf, key, *, didx: int,
+                          filled: Optional[int] = None, injector=None,
                           max_retries: int = 2,
                           retry_backoff_s: float = 1e-3,
                           on_error=None, on_retry=None):
@@ -95,11 +112,14 @@ def dispatch_with_retries(ex, Qbuf, key, *, didx: int, injector=None,
     before the backoff grows.  Returns ``(ids, scores, rounds, dt,
     retries, backoff, spike)`` where ``dt`` already includes the
     injected ``spike`` and accumulated ``backoff`` (virtual seconds);
-    raises `DispatchFailed` past ``max_retries``.
+    raises `DispatchFailed` past ``max_retries``.  ``filled`` is the
+    number of lanes of ``Qbuf`` that hold requests (default: all), which
+    the executor's byte counter and launch span read.
     """
     attempt = 0
     backoff = 0.0
     token = _DIDX.set(didx)
+    filled_token = _FILLED.set(filled)
     try:
         while True:
             injected = (injector.dispatch_error(didx, attempt)
@@ -120,6 +140,7 @@ def dispatch_with_retries(ex, Qbuf, key, *, didx: int, injector=None,
                 attempt += 1
     finally:
         _DIDX.reset(token)
+        _FILLED.reset(filled_token)
     spike = injector.latency_s(didx) if injector is not None else 0.0
     return ids, scores, rounds, dt + spike + backoff, attempt, backoff, spike
 
@@ -335,8 +356,13 @@ class CascadeExecutor:
         self._c_dense_pulls = self.metrics.counter(
             "cascade_dense_pulls_total",
             "Lane-pulls the kernel's dense round-1 phase serves.", keys)
+        self._c_read_bytes = self.metrics.counter(
+            "cascade_table_read_bytes_total",
+            "Table bytes the plan reads a dispatch, at the stored width: "
+            "round 1 once for the batch, later pulls per filled lane.",
+            keys)
         for c in (self._c_dispatch, self._c_recal, self._c_compiles,
-                  self._c_pulls, self._c_dense_pulls):
+                  self._c_pulls, self._c_dense_pulls, self._c_read_bytes):
             c.seed(**lbl)
         self._seen_version = (0 if self.store is None
                               else self.store.version)
@@ -461,6 +487,13 @@ class CascadeExecutor:
                                    adaptive=adaptive)
         self._lane_pulls = self.lanes * flat.pull_steps
         self._lane_dense_pulls = self.lanes * flat.dense_pulls
+        # table bytes a dispatch reads: the dense phase's slabs once for
+        # the batch, then each filled lane's own pulls
+        dtype = jnp.dtype(self.table.dtype)
+        self._table_dtype = dtype.name
+        slab = _slab_bytes(self.plan, dtype.itemsize)
+        self._dense_bytes = int(flat.dense_pulls * slab)
+        self._query_bytes = int((flat.pull_steps - flat.dense_pulls) * slab)
 
         # donate the query buffer: steady-state flushes recycle the same
         # (lanes, N) device allocation (no-op on backends without
@@ -531,9 +564,14 @@ class CascadeExecutor:
         device), ``executor.launch`` (the jitted flush call, where any
         compile lands and is counted in ``cascade_compiles_total``),
         ``executor.wait`` (``block_until_ready``) and ``executor.d2h``
-        (results to the host).  ``seconds`` spans the first three.
+        (results to the host).  ``seconds`` spans the first three.  The
+        launch span also carries ``lanes_filled`` (the lanes the runtime
+        filled, `dispatch_with_retries`'s ``filled``; all of them for a
+        direct call) and the stored ``table_dtype``.
         """
         didx = _DIDX.get()
+        filled = _FILLED.get()
+        filled = self.lanes if filled is None else filled
         t0 = time.perf_counter()
         with warnings.catch_warnings():
             # CPU backends warn that donation is unimplemented; harmless
@@ -541,7 +579,8 @@ class CascadeExecutor:
                                     message=".*[Dd]onat.*")
             with phase("executor.h2d", didx=didx):
                 args = self._flush_args(jnp.asarray(Qbuf), key)
-            with phase("executor.launch", didx=didx), \
+            with phase("executor.launch", didx=didx, lanes_filled=filled,
+                       table_dtype=self._table_dtype), \
                     counting_compiles(self._c_compiles, **self._mlabels):
                 ids, scores, rounds = self._fn(*args)
             with phase("executor.wait", didx=didx):
@@ -550,6 +589,8 @@ class CascadeExecutor:
         self._c_dispatch.inc(**self._mlabels)
         self._c_pulls.inc(self._lane_pulls, **self._mlabels)
         self._c_dense_pulls.inc(self._lane_dense_pulls, **self._mlabels)
+        self._c_read_bytes.inc(self._dense_bytes + filled * self._query_bytes,
+                               **self._mlabels)
         self._h_dispatch.observe(dt * 1e3, **self._mlabels)
         with phase("executor.d2h", didx=didx):
             return (np.asarray(ids), np.asarray(scores),
@@ -962,7 +1003,11 @@ class MIPSServeEngine:
         # fold on the plain dispatch sequence, NOT a registry counter:
         # sampling keys must be invariant to observability wiring
         key = jax.random.fold_in(self._key, self._batch_seq)
-        ids, scores, rounds, dt = self._exec.dispatch(Qbuf, key)
+        token = _FILLED.set(len(batch))
+        try:
+            ids, scores, rounds, dt = self._exec.dispatch(Qbuf, key)
+        finally:
+            _FILLED.reset(token)
         ids = ids[:len(batch)]
         scores = scores[:len(batch)]
         if rounds is not None:
@@ -1715,7 +1760,8 @@ class ServeRuntime:
         try:
             ids, scores, rounds, dt, attempt, backoff, spike = \
                 dispatch_with_retries(
-                    ex, Qbuf, key, didx=didx, injector=self.injector,
+                    ex, Qbuf, key, didx=didx, filled=len(batch),
+                    injector=self.injector,
                     max_retries=self.max_retries,
                     retry_backoff_s=self.retry_backoff_s,
                     on_error=on_error, on_retry=on_retry)

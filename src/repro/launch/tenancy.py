@@ -1195,7 +1195,8 @@ class MultiTenantRuntime:
             try:
                 ids, scores, rounds, dt, attempt, backoff, spike = \
                     dispatch_with_retries(
-                        ex, Qbuf, key, didx=didx, injector=self.injector,
+                        ex, Qbuf, key, didx=didx, filled=len(batch),
+                        injector=self.injector,
                         max_retries=self.max_retries,
                         retry_backoff_s=self.retry_backoff_s,
                         on_error=on_error, on_retry=on_retry)

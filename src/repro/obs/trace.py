@@ -45,12 +45,13 @@ TID_REQ_BASE = 16
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def phase(name: str, **args: int):
+def phase(name: str, **args):
     """Host span ``repro.<name>`` on the profiler's clock.
 
     A ``jax.profiler.TraceAnnotation``: inside a profiler session it
     lands on the host plane of the same ``.xplane.pb`` as the device
-    ops, with ``args`` (plain ints such as ``didx`` or ``rid``) attached;
+    ops, with ``args`` (plain ints such as ``didx`` or ``rid``, or short
+    names such as a ``table_dtype``) attached;
     outside one it records nothing.
     """
     import jax

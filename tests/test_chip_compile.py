@@ -139,3 +139,32 @@ def test_tiers_without_mosaic_lowering_refuse_loudly(tier):
     with pytest.raises(NotImplementedError, match=tier):
         fused_cascade_pallas(V4, qb, code, jnp.zeros((1, 3), jnp.int32),
                              code, **kw)
+
+
+def test_deepseek_v3_bf16_head_compiles_for_v5e(one_chip):
+    """DeepSeek-V3's whole head as the benchmark serves it: 129,280 x
+    7,168 stored in bf16 (16,160 tiles, 14 blocks), K = 1, a verify batch
+    of 16 lanes sharing one column list; round 1 in the dense phase."""
+    n, d, lanes = 129_280, 7_168, 16
+    plan = make_plan(n, d, K=1, eps=0.1, delta=0.1, value_range=0.24,
+                     block=512)
+    flat = flatten_schedule(plan.schedule, final_coverage=True)
+    assert (plan.n_tiles, plan.n_blocks, flat.t_dense) == (16_160, 14, 2)
+    slotcode, rmeta = flat.packed()
+    R, C = plan.tile, plan.block
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(*a):
+        return fused_cascade_batched_pallas(
+            *a, n_arms=plan.n, K=1, t_final=flat.t_final,
+            n_final=flat.n_final, t_dense=flat.t_dense, n_valid=n,
+            interpret=False)
+
+    compiled = jax.jit(run).lower(
+        sds((plan.n_tiles, plan.n_blocks, R, C), jnp.bfloat16),
+        sds((lanes, plan.n_blocks, C), jnp.float32),
+        sds(slotcode.shape, jnp.int32), sds(rmeta.shape, jnp.int32),
+        sds(flat.col_pos.shape, jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

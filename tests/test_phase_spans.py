@@ -15,6 +15,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -80,7 +81,8 @@ for plane in ProfileData.from_file(path).planes:
                     spans.append({{"name": ev.name,
                                    "t0": ev.start_ns,
                                    "t1": ev.start_ns + ev.duration_ns,
-                                   "args": {{k: int(v)
+                                   "args": {{k: v if isinstance(v, str)
+                                             else int(v)
                                              for k, v in ev.stats}}}})
 print(json.dumps({{"devices": len(jax.devices()), "spans": spans}}))
 """
@@ -122,6 +124,13 @@ def test_every_phase_span_is_recorded_nested_with_its_args(
         if s["name"] in DISPATCH_SPANS:
             by_didx.setdefault(s["args"]["didx"], []).append(s)
     assert sorted(by_didx) == [0, 1, 2, 3]  # one per poll, drain included
+    # the launch span names the lanes filled and how the table is stored
+    filled = {}
+    for s in spans:
+        if s["name"] == "repro.executor.launch":
+            assert s["args"]["table_dtype"] == "float32"
+            filled[s["args"]["didx"]] = s["args"]["lanes_filled"]
+    assert filled == {0: 1, 1: 3, 2: LANES, 3: 2}
     for didx, group in by_didx.items():
         group.sort(key=lambda s: s["t0"])
         assert tuple(s["name"] for s in group) == DISPATCH_SPANS, didx
@@ -195,6 +204,62 @@ def test_the_executor_counts_the_plans_pulls_and_the_dense_share():
     for c in (pulls, dense):
         assert [lbl for lbl, _ in c.rows()] == \
             [lbl for lbl, _ in dispatches.rows()]
+
+
+def _read_bytes_per_dispatch(table, filled=None, **kw):
+    """(bytes one dispatch adds to ``cascade_table_read_bytes_total``,
+    the executor): a direct dispatch, or one of ``filled`` lanes."""
+    from repro.launch.engine import dispatch_with_retries
+
+    ex = CascadeExecutor(table, K=1, eps=0.1, delta=0.1, lanes=LANES,
+                         value_range=0.24, **kw)
+    c = ex.metrics.get("cascade_table_read_bytes_total")
+    assert c.total() == 0
+    Q = np.zeros((LANES, DIM), np.float32)
+    if filled is None:
+        ex.dispatch(Q, jax.random.PRNGKey(0))
+    else:
+        dispatch_with_retries(ex, Q, jax.random.PRNGKey(0), didx=0,
+                              filled=filled)
+    dispatches = ex.metrics.get("cascade_dispatches_total")
+    assert [lbl for lbl, _ in c.rows()] == \
+        [lbl for lbl, _ in dispatches.rows()]
+    return c.total(), ex
+
+
+@pytest.mark.parametrize("dtype,precision,itemsize", [
+    ("float32", "fp32", 4), ("bfloat16", "fp32", 2), ("float32", "int8", 1)])
+def test_the_executor_counts_the_table_bytes_its_plan_reads(
+        dtype, precision, itemsize):
+    """Round 1's dense slabs once for the batch, then every lane's own
+    pulls, each slab at the width the table (or its codes) is stored."""
+    from repro.core.boundedme_jax import fused_flat_schedule
+
+    got, ex = _read_bytes_per_dispatch(
+        _table().astype(jnp.dtype(dtype)), precision=precision)
+    flat = fused_flat_schedule(ex.plan, final_exact=True)
+    slab = ex.plan.tile * ex.plan.block * itemsize
+    per_query = flat.pull_steps - flat.dense_pulls
+    assert 0 < flat.dense_pulls and 0 < per_query
+    assert got == flat.t_dense * flat.n_dense * slab + LANES * per_query * slab
+
+
+def test_a_bf16_table_reads_half_the_bytes_of_the_same_plan_in_fp32():
+    fp32, ex32 = _read_bytes_per_dispatch(_table())
+    bf16, ex16 = _read_bytes_per_dispatch(_table().astype(jnp.bfloat16))
+    assert ex16.plan == ex32.plan
+    assert bf16 * 2 == fp32 > 0
+
+
+@pytest.mark.parametrize("filled", [1, 3, LANES])
+def test_a_partial_batch_counts_only_its_filled_lanes(filled):
+    from repro.core.boundedme_jax import fused_flat_schedule
+
+    got, ex = _read_bytes_per_dispatch(_table(), filled=filled)
+    flat = fused_flat_schedule(ex.plan, final_exact=True)
+    slab = ex.plan.tile * ex.plan.block * 4
+    assert got == (flat.dense_pulls
+                   + filled * (flat.pull_steps - flat.dense_pulls)) * slab
 
 
 def test_a_warmed_runtime_compiles_nothing_at_any_occupancy():
