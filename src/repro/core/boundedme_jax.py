@@ -66,8 +66,8 @@ from repro.core.schedule import (FlatSchedule, Schedule, cert_coeffs,
 
 __all__ = ["BlockedPlan", "make_plan", "choose_pull_mode",
            "measured_plan_quant_err", "make_measured_plan",
-           "fused_flat_schedule", "bounded_me_blocked", "bounded_me_batched",
-           "bounded_me_decode"]
+           "fused_flat_schedule", "fused_pull_group", "bounded_me_blocked",
+           "bounded_me_batched", "bounded_me_decode"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -450,6 +450,21 @@ def fused_flat_schedule(plan: BlockedPlan, *, final_exact: bool,
                                        and plan.precision == "fp32"))
 
 
+def fused_pull_group(plan: BlockedPlan, table_dtype) -> int:
+    """G: the most survivor tiles one fused-kernel grid step pulls after
+    round 1, for ``plan``'s tiles as the kernel reads them (the table's
+    own dtype on the fp32 tier, int8 codes, nibble-packed int4 or uint8
+    pq codes on the others)."""
+    from repro.kernels.fused_cascade import pull_group_size
+
+    Cs, dtype = {"fp32": (plan.block, table_dtype),
+                 "int8": (plan.block, jnp.int8),
+                 "int4": (plan.block // 2, jnp.int8),
+                 "pq": (plan.block // plan.pq_subdims, jnp.uint8),
+                 }[plan.precision]
+    return pull_group_size(plan.tile, Cs, dtype)
+
+
 def _fused_call(V4, qb_or_Qb, perm_or_perms, *, plan: BlockedPlan,
                 final_exact: bool, batched: bool, k_out: Optional[int] = None,
                 n_valid=None, vscale=None, qscale=None, codebook=None,
@@ -477,7 +492,7 @@ def _fused_call(V4, qb_or_Qb, perm_or_perms, *, plan: BlockedPlan,
     # the dense phase's round-1 columns, then one column per step
     cols = perm_or_perms[..., jnp.asarray(flat.col_pos)]
     cert = (jnp.asarray(cert_coeffs(plan.schedule)) if adaptive else None)
-    return fn(V4, qb_or_Qb, jnp.asarray(slotcode), jnp.asarray(rmeta), cols,
+    return fn(V4, qb_or_Qb, slotcode, jnp.asarray(rmeta), cols,
               n_arms=plan.n, K=plan.K, t_final=flat.t_final,
               n_final=flat.n_final, t_dense=flat.t_dense, k_out=k_out,
               n_valid=n_valid,

@@ -18,8 +18,8 @@ import numpy as np
 from repro.core import bounds
 
 __all__ = ["Round", "Schedule", "FlatSchedule", "make_schedule",
-           "flatten_schedule", "cert_coeffs", "pulls_through_round",
-           "SLOT_MASK", "END_BIT", "PULL_BIT"]
+           "flatten_schedule", "group_steps", "cert_coeffs",
+           "pulls_through_round", "SLOT_MASK", "END_BIT", "PULL_BIT"]
 
 # bit-packing of the per-step word handed to the fused kernel (SMEM is the
 # scarcest resource on-chip: one int32 per step instead of a wide row)
@@ -203,6 +203,45 @@ class FlatSchedule:
                          self.n_keep[ends]], axis=1).reshape(-1, 3)
         meta = np.concatenate([meta, np.zeros((1, 3), np.int64)], axis=0)
         return code.astype(np.int32), meta.astype(np.int32)
+
+    def groups(self, G: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The fused kernel's per-query grid steps at ``G`` tiles a step.
+
+        Returns ``(first, size)``: grid step ``j`` runs the steps
+        ``first[j] .. first[j] + max(size[j], 1) - 1`` of this schedule and
+        pulls ``size[j]`` tiles (0 on a no-pull step); see `group_steps`.
+        """
+        _, first = group_steps(self.packed()[0], G)
+        size = np.diff(np.r_[first, self.n_steps]) * self.is_pull[first]
+        return first, size.astype(np.int32)
+
+
+def group_steps(slotcode: np.ndarray, G: int) -> Tuple[np.ndarray,
+                                                       np.ndarray]:
+    """Group a packed per-step list into the fused kernel's grid steps.
+
+    After round 1 each column of a round pulls the survivor slots 0 ..
+    n_surv - 1 in order (blocks outermost, slots innermost), and so do
+    the final-coverage steps over the ``n_final`` survivors.  A grid step
+    takes the slots ``s0 .. s0 + n - 1`` of one column, with ``s0`` a
+    multiple of ``G`` and ``n = min(G, n_surv - s0)``: it never crosses a
+    column change or a round end, so the survivor list is fixed within it.
+    A no-pull step stays a grid step of its own.  The kernel derives
+    ``n`` from ``s0`` and the round's ``n_surv``, so a grouped word is the
+    group's first word with the END_BIT of its last.
+
+    Returns ``(code (S_g,), first (S_g,))``: the grouped words and the
+    index of each group's first step in ``slotcode``.
+    """
+    code = np.asarray(slotcode).astype(np.int64)
+    pull = (code & PULL_BIT) != 0
+    first = np.flatnonzero(~pull | ((code & SLOT_MASK) % G == 0))
+    if code.size and (first.size == 0 or first[0] != 0):
+        raise ValueError("a per-step list must open on a no-pull step or "
+                         "on slot 0 of a column")
+    last = np.r_[first[1:], code.size] - 1
+    out = (code[first] & ~END_BIT) | (code[last] & END_BIT)
+    return out.astype(np.int32), first.astype(np.int32)
 
 
 def flatten_schedule(sched: Schedule, *,

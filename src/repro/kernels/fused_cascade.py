@@ -14,11 +14,15 @@ Because the round structure is data-independent (`repro.core.schedule`), the
   * after round 1 the (n_tiles, R) f32 accumulator stays VMEM-resident
     and the survivor index set SMEM-resident across all rounds — neither
     round-trips to HBM;
-  * each later grid step manually DMAs exactly one surviving (R, C) tile
-    of V from HBM (double-buffered: the next step's tile is prefetched
-    while the current tile-dot runs).  Only the bytes the bandit pulls
-    ever cross the memory bus, and the survivor indices live in SMEM, so
-    the "gather" costs no HBM traffic at all;
+  * each later grid step pulls a group of up to ``G`` surviving (R, C)
+    tiles of one column of one round (`repro.core.schedule.group_steps`):
+    the previous step starts all of the group's tile DMAs into one half of
+    a ``(2, G, R, Cs)`` VMEM ring, on one semaphore, and the step waits on
+    each of them, then adds each tile's part to its own accumulator row.
+    ``G`` comes from the stored tile (`pull_group_size`: 16 tiles of (8,
+    512) at fp32, bf16 or int8).  Only the bytes the bandit pulls ever
+    cross the memory bus, and the survivor indices live in SMEM, so the
+    "gather" costs no HBM traffic at all;
   * at round boundaries the tile elimination runs *inside* the kernel: the
     survivors' masked tile-max means are written to SMEM in slot order and
     stably sorted descending there (a bottom-up merge sort — exactly
@@ -33,42 +37,54 @@ list for the batch (``cols (t_dense + S,)``, the decode path) each step
 scores all B queries, ``G1 = ceil(n_tiles / T_blk) * t_dense``; with a
 list per query (``cols (B, t_dense + S)``) each scores one query and
 ``G1`` is B times that.  Query ``b``'s own steps follow, ``G1 + b * S``
-to ``G1 + (b + 1) * S - 1``: the first one re-initializes its survivor
-state, reads its round-1 sums back and ends round 1 (elimination, then
-certification when adaptive) without a pull; rounds 2 and after run as
-before.  VMEM holds one query's accumulator at a time, so the dense phase
-stages each finished tile group's ``(B, T_blk, R)`` sums (the first R of
-128 lanes) to an HBM buffer (an extra ``pl.ANY`` output the wrapper drops) and each query's first
-step DMAs its slice back.  A schedule with no rounds has no dense phase
+to ``G1 + (b + 1) * S - 1``, where ``S`` counts the grouped steps: the
+first one re-initializes its survivor state, reads its round-1 sums back
+and ends round 1 (elimination, then certification when adaptive) without
+a pull; each later one pulls a group of survivor tiles or, in a round
+that pulls nothing, only eliminates.  VMEM holds one query's accumulator
+at a time, so the dense phase stages each finished tile group's ``(B,
+T_blk, R)`` sums (the first R of 128 lanes) to an HBM buffer (an extra
+``pl.ANY`` output the wrapper drops) and each query's first step DMAs its
+slice back.  A schedule with no rounds has no dense phase
 and its sums start at zero.  The single-query entry point is the same
 kernel at B = 1.
 
-The dense phase does the same arithmetic as a pull step, so results stay
-bitwise equal to the jnp fallbacks in interpret mode: per (query, tile,
-row) the fp32 parts are `f32_tile_dot` and int8/int4 parts the same exact
-integer dot dequantized with ``vscale * qscale``, added from 0.0 in
-permutation order; adaptive mode also sums ``part * part``.
+The dense phase and a group step do the same arithmetic as the
+fallbacks' pulls, so results stay bitwise equal to the jnp fallbacks in
+interpret mode: per (query, tile, row) the fp32 parts are `f32_tile_dot`
+(lane-dense over `_dense_chunk` tiles a pass when C > 128) and int8/int4
+parts the same exact integer dot dequantized with ``vscale * qscale``,
+added from 0.0 in permutation order; adaptive mode also sums ``part *
+part``.  A group's tiles are distinct, so each tile still sums its columns
+in order, and elimination and certification still run only at a round's
+last step.
 
 Mosaic memory rules shape the layout: scalars are only loaded and stored in
 SMEM, so every per-step scalar (survivor ids, sort keys, round cursor,
 adaptive lanes, the (ids, vals, rounds_used) outputs) lives there, and VMEM
-holds only vectors — the accumulator, the DMA double buffer, the query
+holds only vectors — the accumulator, the pull ring, the query
 block, the certification/extraction row buffers.  Reductions over those
 buffers run in row chunks so the compiled loop body stays small.  VMEM and
 SMEM budgets are computed from the shapes before lowering, and a shape
 that cannot fit one TensorCore is refused with a `ValueError`.
 
-Scalar-prefetch operands (SMEM, 1-D):
-  slotcode (S,)           packed slot | PULL_BIT | END_BIT per step
+Scalar-prefetch operands (SMEM, 1-D), after the entry points group the
+caller's per-step list (host data) with `group_steps`:
+  slotcode (S,)           packed first slot | PULL_BIT | END_BIT per
+                          grouped step; a group's size is
+                          ``min(G, n_surv - slot)``, ``n_surv`` from
+                          ``rounds_meta`` (``n_final`` after the last
+                          round, for the final-coverage steps)
   rounds_meta (3(r+1),)   (t_cum, n_surv, n_keep) per round, consumed at
                           end steps
   cert (2(r+1),)          adaptive only: per-round certification-radius
                           coefficients (a_l, b_l) from
                           `repro.core.schedule.cert_coeffs` (DESIGN.md §12)
   cols (t_dense+S,) or    column-block ids: round 1's ``t_dense`` dense
-       (B*(t_dense+S),)   columns, then one per step
-                          (``perm[flat.col_pos]``); the first form when
-                          the batch shares one permutation
+       (B*(t_dense+S),)   columns, then one per grouped step (the
+                          caller's ``perm[flat.col_pos]``, kept at each
+                          group's first step); the first form when the
+                          batch shares one permutation
   nvalid (1,)             rows >= nvalid are masked out of every ranking
                           (tile padding AND caller padding, e.g. a padded
                           vocab or a ragged shard — DESIGN.md §7); may be
@@ -110,7 +126,7 @@ each row's radius is ``a_l sqrt(max(Vhat, 0)) + b_l`` on the block-mean
 scale, with ``Vhat`` from a second running-M2 accumulator when the
 schedule's bound family is 'bernstein' — and if the top-``k_cert`` rows'
 lower bounds clear every other survivor's upper bound, the query's
-remaining pull steps (tile DMA + accumulate + prefetch) become masked
+remaining group steps (tile DMAs + accumulate + prefetch) become masked
 no-ops.  Eliminations keep running on the frozen accumulator (every
 survivor froze at the same pull count, so scheduled-denominator means are a
 positive rescale of the true means and every later ranking is unchanged),
@@ -118,9 +134,9 @@ the final extraction normalizes by the *actual* pull count, and a third
 output reports per-query ``rounds_used``.
 
 Coordinate-sampling pull mode (DESIGN.md §14): the kernel is generic in
-the feature-tile width ``C`` — a pull step DMAs one surviving ``(R, C)``
-feature tile ``V_ref.at[tile, col]`` whether the plan calls that tile a
-'row'-mode block (C = min(512, d)) or a 'coord'-mode narrow feature tile
+the feature-tile width ``C`` — a group step DMAs surviving ``(R, C)``
+feature tiles ``V_ref.at[tile, col]`` whether the plan calls them
+'row'-mode blocks (C = min(512, d)) or 'coord'-mode narrow feature tiles
 (C = coord_block, default 128 = the TPU lane width).  ``pull_mode='coord'``
 needs ZERO kernel changes: `make_plan` re-blocks the feature axis so the
 flat schedule's column ids index ``n_blocks = ceil(d / coord_block)``
@@ -134,13 +150,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import f32_tile_dot, pq_tile_dot, unpack_int4
-from repro.core.schedule import END_BIT, PULL_BIT, SLOT_MASK
+from repro.core.schedule import END_BIT, PULL_BIT, SLOT_MASK, group_steps
 
-__all__ = ["fused_cascade_pallas", "fused_cascade_batched_pallas"]
+__all__ = ["fused_cascade_pallas", "fused_cascade_batched_pallas",
+           "pull_group_size"]
 
 #: v5e: 128 MiB of VMEM and 1 MiB of SMEM per TensorCore; the kernel asks
 #: for what its shapes need and refuses shapes that cannot fit.
@@ -148,6 +166,7 @@ _VMEM_CAP = 100 * 2**20
 _SMEM_CAP_WORDS = 240_000
 _ROW_CHUNK = 512        # rows per step of the chunked VMEM reductions
 _DENSE_DMA_BYTES = 2**20   # VMEM bytes of one dense-phase slab DMA
+_RING_BYTES = 2**18        # VMEM bytes of one half of the pull ring
 _STAGE_ROWS = 2048         # staged rows a query reads back per DMA
 _BIG = 2**30            # "no index" sentinel of the arg-reductions
 
@@ -287,7 +306,7 @@ def _dense_chunk(R):
 def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                  qkind="none", adaptive=False, track_var=False, k_cert=1,
                  n_rounds=0, shared_cols=True, ch=8, ch_f=8, q_dtype=None,
-                 t_dense, T_blk, xch):
+                 t_dense, T_blk, xch, G):
     """Build the kernel body for the flat grid of ``G1 + B * S`` steps.
 
     ``qkind`` selects the pull arithmetic (DESIGN.md §10): 'none' (f32
@@ -308,7 +327,8 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
     ``q_dtype`` is the true dtype of a query block passed 32-bit wide.
     ``t_dense`` round-1 columns run in the dense phase, ``T_blk`` tiles
     a step, and each query reads its staged sums back ``xch`` rows a DMA;
-    ``S`` counts each query's own steps.
+    ``S`` counts each query's own grid steps, each pulling up to ``G``
+    same-column survivor tiles (`repro.core.schedule.group_steps`).
     """
     dims = (((1,), (1,)), ((), ()))        # (1, C) x (R, C) -> (1, R)
     n_tblk = -(-n_tiles // T_blk) if t_dense else 0
@@ -558,30 +578,82 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
                 st[2] = t_final            # t_stop (actual pulls)
                 rused_ref[b] = n_rounds
 
+        def group_len(s0, r):
+            """Tiles of the group opening at slot ``s0`` in the round at
+            cursor ``r``: the final-coverage steps follow the last round."""
+            n_surv = rmeta_ref[3 * r + 1]
+            if n_rounds:
+                n_surv = jnp.where(r < n_rounds, n_surv, n_final)
+            else:
+                n_surv = n_final
+            return jnp.minimum(G, n_surv - s0)
+
+        def ring_copy(tile, cl, h, t):   # one tile into slot t of half h
+            return pltpu.make_async_copy(V_ref.at[tile, cl], vbuf.at[h, t],
+                                         sem.at[h])
+
+        def start_group(bb, ii, h, r, active=True, ident=False):
+            """Start every tile DMA of query ``bb``'s own step ``ii`` into
+            ring half ``h``; ``ident``: a fresh query's identity slots."""
+            ncode = code_ref[ii]
+
+            @pl.when(jnp.logical_and(active, (ncode & PULL_BIT) != 0))
+            def _():
+                s0 = ncode & SLOT_MASK
+                cl = col_at(bb, ii)
+
+                def one(t, _):
+                    ring_copy(s0 + t if ident else surv[s0 + t], cl, h,
+                              t).start()
+                    return 0
+                jax.lax.fori_loop(0, group_len(s0, r), one, 0)
+
         @pl.when(g == max(G1 - 1, 0))
         def _start_first():  # every later step is prefetched by the previous
-            ncode = code_ref[0]
-
-            @pl.when((ncode & PULL_BIT) != 0)
-            def _():   # query 0 starts on identity slots
-                pltpu.make_async_copy(
-                    V_ref.at[ncode & SLOT_MASK, col_at(0, 0)], vbuf.at[0],
-                    sem.at[0]).start()
+            start_group(0, 0, 0, 0, ident=True)   # query 0's identity slots
 
         # a certified (inactive) query's remaining pulls are masked no-ops:
         # no DMA wait, no accumulate — and _warm below starts no DMA for it
         do_pull = jnp.logical_and(pull, st[1] == 1) if adaptive else pull
 
         @pl.when(do_pull)
-        def _pull():
-            tile = surv[slot]
-            pltpu.make_async_copy(V_ref.at[tile, col], vbuf.at[dslot],
-                                  sem.at[dslot]).wait()
-            part = tile_part(vbuf[dslot], q_block(b, col), col, tile, b)
-            acc[pl.ds(tile, 1), :] = acc[pl.ds(tile, 1), :] + part
-            if track_var:
-                acc2[pl.ds(tile, 1), :] = (acc2[pl.ds(tile, 1), :]
-                                           + part * part)
+        def _pull():   # the group's tiles, each added to its own row
+            n = group_len(slot, st[0])
+
+            def wait(t, _):
+                ring_copy(0, col, dslot, t).wait()
+                return 0
+            jax.lax.fori_loop(0, n, wait, 0)
+            qcol = q_block(b, col)
+
+            def add(t, part):
+                rows = pl.ds(surv[slot + t], 1)
+                acc[rows, :] = acc[rows, :] + part
+                if track_var:
+                    acc2[rows, :] = acc2[rows, :] + part * part
+
+            if qkind == "none":
+                def per_chunk(k, _):   # `chunk` tiles a pass, lane-dense
+                    t0 = pl.multiple_of(k * chunk, chunk)
+                    slab = vbuf[dslot, pl.ds(t0, chunk)]
+                    if C > 128:
+                        sums = _tile_dot_lanes(slab, qcol)
+                        parts = [sums[:, t * R:(t + 1) * R]
+                                 for t in range(chunk)]
+                    else:
+                        sums = f32_tile_dot(slab, qcol)     # (chunk, R)
+                        parts = [sums[t:t + 1] for t in range(chunk)]
+                    for t, part in enumerate(parts):
+                        pl.when(t0 + t < n)(
+                            functools.partial(add, t0 + t, part))
+                    return 0
+                jax.lax.fori_loop(0, (n + chunk - 1) // chunk, per_chunk, 0)
+            else:
+                def per_tile(t, _):    # the quantized tiers: a tile a pass
+                    add(t, tile_part(vbuf[dslot, t], qcol, col,
+                                     surv[slot + t], b))
+                    return 0
+                jax.lax.fori_loop(0, n, per_tile, 0)
 
         @pl.when(end)
         def _eliminate():
@@ -647,30 +719,17 @@ def _make_kernel(*, R, C, K, n_tiles, t_final, n_final, S, B, n_blocks,
 
             st[0] = r + 1
 
-        # prefetch the next step's tile (post-elimination survivor indices)
+        # prefetch the next step's group (post-elimination survivor ids);
+        # frozen queries prefetch nothing
         @pl.when(jnp.logical_and(in_q, i < S - 1))
         def _warm():
-            ncode = code_ref[i + 1]
-            npull = (ncode & PULL_BIT) != 0
-            if adaptive:      # frozen queries prefetch nothing
-                npull = jnp.logical_and(npull, st[1] == 1)
-
-            @pl.when(npull)
-            def _():
-                pltpu.make_async_copy(
-                    V_ref.at[surv[ncode & SLOT_MASK], col_at(b, i + 1)],
-                    vbuf.at[1 - dslot], sem.at[1 - dslot]).start()
+            start_group(b, i + 1, 1 - dslot, st[0],
+                        active=(st[1] == 1) if adaptive else True)
 
         @pl.when(jnp.logical_and(jnp.logical_and(in_q, i == S - 1),
                                  b < B - 1))
         def _warm_next_query():  # next query restarts on identity slots
-            ncode = code_ref[0]
-
-            @pl.when((ncode & PULL_BIT) != 0)
-            def _():
-                pltpu.make_async_copy(
-                    V_ref.at[ncode & SLOT_MASK, col_at(b + 1, 0)],
-                    vbuf.at[0], sem.at[0]).start()
+            start_group(b + 1, 0, 0, 0, ident=True)
 
         @pl.when(jnp.logical_and(in_q, i == S - 1))
         def _finalize():
@@ -732,10 +791,55 @@ def _resolve_qkind(Cs, vscale, qscale, codebook, packed_int4):
     return ("int8" if vscale is not None else "none"), Cs
 
 
-def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
-           n_final, t_dense, k_out, n_valid, vscale, qscale, codebook,
-           packed_int4, cert, k_cert, track_var, interpret):
-    """Shared body of the single-query and batched entry points."""
+def pull_group_size(R: int, Cs: int, dtype) -> int:
+    """G: the most survivor tiles one grid step of rounds 2+ pulls.
+
+    Taken from the stored tile as the dense phase's ``T_blk`` is: whole
+    lane-dense passes of `_dense_chunk` tiles, about ``_RING_BYTES`` of
+    VMEM a ring half (16 tiles of (8, 512) at fp32, bf16 or int8).
+    """
+    unit = _dense_chunk(R)
+    tile = _vmem_bytes((R, Cs), dtype)
+    return unit * max(1, _RING_BYTES // (unit * tile))
+
+
+def _fused(V4, Qb, slotcode, rounds_meta, cols, *, t_dense, **kw):
+    """Shared body of the single-query and batched entry points.
+
+    Groups the per-step list on the host (`group_steps`), keeps the
+    columns of each group's first step, and launches the kernel.
+    """
+    if isinstance(slotcode, jax.core.Tracer):
+        raise ValueError(
+            "slotcode must be host data (numpy): the kernel's grid is "
+            "derived from it")
+    code = np.asarray(slotcode)
+    B = Qb.shape[0]
+    S = code.shape[0]
+    cols = jnp.asarray(cols, jnp.int32)
+    W = t_dense + S                  # dense columns, then one per step
+    if cols.shape not in ((W,), (B, W)):
+        raise ValueError(
+            f"cols must be (t_dense + S,) or (B, t_dense + S) with t_dense "
+            f"= {t_dense}, S = {S} steps and B = {B}; got {cols.shape} "
+            f"(`FlatSchedule.col_pos` gives the positions)")
+    _, _, R, Cs = V4.shape
+    G = pull_group_size(R, Cs, V4.dtype)
+    code_g, first = group_steps(code, G)
+    keep = np.concatenate([np.arange(t_dense), t_dense + first])
+    return _launch(V4, Qb, jnp.asarray(code_g), rounds_meta,
+                   jnp.take(cols, jnp.asarray(keep, jnp.int32), axis=-1),
+                   t_dense=t_dense, G=G, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_arms", "K", "t_final", "n_final", "t_dense", "k_out", "k_cert",
+    "track_var", "packed_int4", "interpret", "G"))
+def _launch(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
+            n_final, t_dense, k_out, n_valid, vscale, qscale, codebook,
+            packed_int4, cert, k_cert, track_var, interpret, G):
+    """One ``pallas_call`` over the grouped steps (``slotcode`` (S_g,),
+    ``cols`` (t_dense + S_g,) or (B, t_dense + S_g)), ``G`` tiles a step."""
     n_tiles, n_blocks, R, Cs = V4.shape
     qkind, C = _resolve_qkind(Cs, vscale, qscale, codebook, packed_int4)
     if not interpret and qkind in ("int4", "pq"):
@@ -749,14 +853,8 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
         n_valid = n_arms
     B = Qb.shape[0]
     S = slotcode.shape[0]
-    cols = jnp.asarray(cols, jnp.int32)
     shared_cols = cols.ndim == 1
-    W = t_dense + S                  # dense columns, then one per step
-    if cols.shape not in ((W,), (B, W)):
-        raise ValueError(
-            f"cols must be (t_dense + S,) or (B, t_dense + S) with t_dense "
-            f"= {t_dense}, S = {S} steps and B = {B}; got {cols.shape} "
-            f"(`FlatSchedule.col_pos` gives the positions)")
+    W = t_dense + S
     n_rounds = rounds_meta.shape[0] - 1
     ch = min(_ROW_CHUNK, _round_up(n_tiles, 8))
     n_rows = _round_up(n_tiles, ch)
@@ -811,7 +909,7 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
         # accumulator, plus the running M2 feeding 'bernstein' radii
         scratch = [pltpu.VMEM((n_rows, R), jnp.float32)] * n_sums
         scratch += [
-            pltpu.VMEM((2, R, Cs), V4.dtype),     # double-buffered tile DMA
+            pltpu.VMEM((2, G, R, Cs), V4.dtype),  # the pull ring: 2 groups
             pltpu.SMEM((n_tiles,), jnp.int32),    # survivor tile ids
             pltpu.SMEM((n_tiles,), jnp.float32),  # merge-sort ping-pong keys
             pltpu.SMEM((n_tiles,), jnp.int32),    # merge-sort ping-pong ids
@@ -892,7 +990,7 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
                           track_var=track_var, k_cert=k_cert,
                           n_rounds=n_rounds, shared_cols=shared_cols,
                           ch=ch, ch_f=ch_f, q_dtype=q_dtype,
-                          t_dense=t_dense, T_blk=T_blk, xch=xch)
+                          t_dense=t_dense, T_blk=T_blk, xch=xch, G=G)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -905,11 +1003,6 @@ def _fused(V4, Qb, slotcode, rounds_meta, cols, *, n_arms, K, t_final,
     return (ids, vals, out[2]) if adaptive else (ids, vals)
 
 
-_STATIC = ("n_arms", "K", "t_final", "n_final", "t_dense", "k_out",
-           "k_cert", "track_var", "packed_int4", "interpret")
-
-
-@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
                          K: int, t_final: int, n_final: int, t_dense: int,
                          k_out: int = None, n_valid=None,
@@ -925,8 +1018,10 @@ def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
     S) with ``codebook``.
     qb:  (n_blocks, C) blocked query (VMEM-resident) — f32 on the fp32
     AND pq paths, int8 on the int8/int4 (W4A8) paths.
-    slotcode/rounds_meta: see `FlatSchedule.packed`; cols (t_dense + S,):
-    the dense phase's round-1 column-block ids, then one per step
+    slotcode/rounds_meta: see `FlatSchedule.packed`; ``slotcode`` is host
+    data (numpy), which this entry point groups into the kernel's grid
+    steps (`repro.core.schedule.group_steps`); cols (t_dense + S,): the
+    dense phase's round-1 column-block ids, then one per step
     (``perm[flat.col_pos]``); ``t_dense`` is `FlatSchedule.t_dense`.
     k_out: number of final candidates extracted in-kernel (default K).
     Shard-local callers ask for k_out > K so the K winners come back with a
@@ -962,7 +1057,6 @@ def fused_cascade_pallas(V4, qb, slotcode, rounds_meta, cols, *, n_arms: int,
     return tuple(x[0] for x in out)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_cascade_batched_pallas(V4, Qb, slotcode, rounds_meta, cols, *,
                                  n_arms: int, K: int, t_final: int,
                                  n_final: int, t_dense: int,

@@ -356,13 +356,18 @@ class CascadeExecutor:
         self._c_dense_pulls = self.metrics.counter(
             "cascade_dense_pulls_total",
             "Lane-pulls the kernel's dense round-1 phase serves.", keys)
+        self._c_pull_groups = self.metrics.counter(
+            "cascade_pull_groups_total",
+            "Lane grid steps that pull a group of same-column survivor "
+            "tiles after round 1, per dispatch.", keys)
         self._c_read_bytes = self.metrics.counter(
             "cascade_table_read_bytes_total",
             "Table bytes the plan reads a dispatch, at the stored width: "
             "round 1 once for the batch, later pulls per filled lane.",
             keys)
         for c in (self._c_dispatch, self._c_recal, self._c_compiles,
-                  self._c_pulls, self._c_dense_pulls, self._c_read_bytes):
+                  self._c_pulls, self._c_dense_pulls, self._c_pull_groups,
+                  self._c_read_bytes):
             c.seed(**lbl)
         self._seen_version = (0 if self.store is None
                               else self.store.version)
@@ -401,7 +406,8 @@ class CascadeExecutor:
         (and therefore recompile) on the dynamic path.
         """
         from repro.core.boundedme_jax import (bounded_me_decode,
-                                              fused_flat_schedule, make_plan)
+                                              fused_flat_schedule,
+                                              fused_pull_group, make_plan)
 
         self._plan_value_range = float(value_range)
         mesh, model_axis = self.mesh, self._model_axis
@@ -490,6 +496,8 @@ class CascadeExecutor:
         # table bytes a dispatch reads: the dense phase's slabs once for
         # the batch, then each filled lane's own pulls
         dtype = jnp.dtype(self.table.dtype)
+        _, size = flat.groups(fused_pull_group(self.plan, dtype))
+        self._lane_pull_groups = self.lanes * int((size > 0).sum())
         self._table_dtype = dtype.name
         slab = _slab_bytes(self.plan, dtype.itemsize)
         self._dense_bytes = int(flat.dense_pulls * slab)
@@ -589,6 +597,7 @@ class CascadeExecutor:
         self._c_dispatch.inc(**self._mlabels)
         self._c_pulls.inc(self._lane_pulls, **self._mlabels)
         self._c_dense_pulls.inc(self._lane_dense_pulls, **self._mlabels)
+        self._c_pull_groups.inc(self._lane_pull_groups, **self._mlabels)
         self._c_read_bytes.inc(self._dense_bytes + filled * self._query_bytes,
                                **self._mlabels)
         self._h_dispatch.observe(dt * 1e3, **self._mlabels)

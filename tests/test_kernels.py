@@ -230,7 +230,7 @@ def _grid_steps(fn, *args):
 
 
 def _dense_case(bound="hoeffding", precision="fp32", n=800, N=4096,
-                block=512, B=3, eps=0.7, seed=0):
+                block=512, B=3, eps=0.7, seed=0, pull_mode="row"):
     """A table whose 100 tiles (800 rows) make two dense tile groups of
     64 at fp32 and block 512, the second one short."""
     from repro.core.boundedme_jax import make_plan
@@ -240,6 +240,7 @@ def _dense_case(bound="hoeffding", precision="fp32", n=800, N=4096,
     Q = rng.normal(size=(B, N)).astype(np.float32)
     plan = make_plan(n, N, K=2, eps=eps, delta=0.1, value_range=8.0,
                      block=block, bound=bound, precision=precision,
+                     pull_mode=pull_mode,
                      quant_err=0.05 if precision == "pq" else None)
     return V, Q, plan
 
@@ -251,15 +252,20 @@ def _assert_same(a, b, what):
 
 
 def dense_parity(layout, B, mode, precision="fp32", n=800, N=4096,
-                 block=512, eps=0.7):
-    """Kernel (dense round 1) vs both jnp fallbacks, bit for bit."""
+                 block=512, eps=0.7, pull_mode="row", lean=None):
+    """Kernel (dense round 1) vs both jnp fallbacks, bit for bit.
+
+    ``lean``: queries ``j`` of ``(j, row, weight)`` lean on one row, so
+    they certify early in adaptive mode.  Returns the kernel's output."""
     from repro.core.boundedme_jax import bounded_me_batched, \
         bounded_me_decode
 
     adaptive = mode != "fixed"
     bound = "bernstein" if mode == "bernstein" else "hoeffding"
     V, Q, plan = _dense_case(bound, precision, n=n, N=N, block=block, B=B,
-                             eps=eps)
+                             eps=eps, pull_mode=pull_mode)
+    for j, row, w in lean or ():
+        Q[j] += w * V[row]
     assert plan.schedule.rounds[0].t_cum < plan.n_blocks   # later pulls
     key = jax.random.PRNGKey(B)
     if layout == "shared":   # the decode path: one permutation, (S,) cols
@@ -283,6 +289,7 @@ def dense_parity(layout, B, mode, precision="fp32", n=800, N=4096,
         out_k = bounded_me_batched(V, Q, keys, use_pallas=True, **kw)
         out_s = bounded_me_batched(V, Q, keys, use_pallas=False, **kw)
         _assert_same(out_k, out_s, "vs scan path")
+    return out_k
 
 
 class TestDenseRoundOne:
@@ -296,18 +303,27 @@ class TestDenseRoundOne:
 
     @pytest.mark.parametrize("layout", ["shared", "per_query"])
     def test_grid_runs_round_one_once(self, layout):
-        """G1 + B (S - S1 + 1) steps: S1 is round 1's pull steps of the
-        per-query layout, and G1 its dense steps, 64 tiles (1 MiB) each."""
+        """G1 + B S_g steps: G1 is round 1's dense steps, 64 tiles (1 MiB)
+        each, and S_g a query's own: round 1's end, then each later
+        round's columns in groups of up to G = 16 survivor tiles (one step
+        for a round that pulls nothing)."""
         from repro.core.boundedme_jax import (_run_batched_fused,
-                                              _run_decode)
+                                              _run_decode,
+                                              fused_flat_schedule)
+        from repro.kernels.fused_cascade import pull_group_size
 
         B = 3
         V, Q, plan = _dense_case(B=B)
         rounds = plan.schedule.rounds
-        S = sum(max(1, r.n_arms * r.t_new) for r in rounds)  # one a query
-        S1 = rounds[0].n_arms * rounds[0].t_new
         t1 = rounds[0].t_cum
-        assert (plan.n_tiles, t1) == (100, 7) and S1 == 700
+        assert (plan.n_tiles, t1) == (100, 7)
+        G = pull_group_size(plan.tile, plan.block, jnp.float32)
+        assert G == 16
+        S_g = 1 + sum(r.t_new * -(-r.n_arms // G) if r.t_new else 1
+                      for r in rounds[1:])
+        assert S_g == len(fused_flat_schedule(
+            plan, final_exact=False).groups(G)[0])
+        assert S_g < sum(max(1, r.n_arms * r.t_new) for r in rounds[1:])
         groups = 1 if layout == "shared" else B
         G1 = groups * 2 * t1          # ceil(100 / 64) tile groups
         key = jax.random.PRNGKey(0)
@@ -322,7 +338,7 @@ class TestDenseRoundOne:
                 lambda V, Q, k: _run_batched_fused(V, Q, k, plan=plan,
                                                    final_exact=False),
                 V, Q, jax.random.split(key, B))
-        assert steps == G1 + B * (S - S1 + 1)
+        assert steps == G1 + B * S_g
 
     def test_no_rounds_has_no_dense_phase(self):
         """K >= n_tiles: nothing to eliminate, sums start at zero."""
@@ -360,6 +376,95 @@ class TestDenseRoundOne:
         flat = fused_flat_schedule(plan, final_exact=True)
         assert (flat.dense_pulls, flat.pull_steps) == (16_000, 29_220)
         assert flat.n_steps == 29_224 - 16_000 + 1
+
+
+#: 2,000 rows (250 tiles) x 8,192 at block 512, K = 2: rounds 2+ pull 126,
+#: 64, 33, 17, 9 and 5 survivors (fp32), so groups of 16 end short and
+#: some rounds have fewer survivors than a group holds
+_GROUPED = dict(n=2000, N=8192, eps=2.0)
+
+
+def _short_groups(plan, G):
+    """Rounds 2+ that pull: one with a short last group, one under G."""
+    pulling = [r.n_arms for r in plan.schedule.rounds[1:] if r.t_new]
+    return (any(a % G for a in pulling if a > G)
+            and any(a < G for a in pulling))
+
+
+class TestGroupedPulls:
+    """Rounds 2+ pulled in groups of same-column survivor tiles, one grid
+    step a group, bit for bit as the jnp fallbacks pull them one by one."""
+
+    @pytest.mark.parametrize("mode", ["fixed", "hoeffding", "bernstein"])
+    @pytest.mark.parametrize("B", [1, 3, 8])
+    @pytest.mark.parametrize("layout", ["shared", "per_query"])
+    @pytest.mark.parametrize("precision", ["fp32", "int8"])
+    def test_grouped_pulls_bitwise_vs_fallbacks(self, precision, layout, B,
+                                                mode):
+        from repro.kernels.fused_cascade import pull_group_size
+
+        _, _, plan = _dense_case(
+            "bernstein" if mode == "bernstein" else "hoeffding", precision,
+            block=512, B=B, **_GROUPED)
+        dtype = jnp.float32 if precision == "fp32" else jnp.int8
+        assert _short_groups(plan, pull_group_size(8, 512, dtype))
+        dense_parity(layout, B, mode, precision, **_GROUPED)
+
+    @pytest.mark.parametrize("mode,w", [("hoeffding", 0.3),
+                                        ("bernstein", 3.5)])
+    def test_a_query_that_freezes_mid_schedule(self, mode, w):
+        """Query 0 leans on two rows and certifies after a few grouped
+        rounds; its batchmates pull on after it froze."""
+        rounds_used = dense_parity("shared", 3, mode,
+                                   lean=[(0, 77, w), (0, 1234, w)],
+                                   **_GROUPED)[2]
+        n_rounds = len(_dense_case(
+            "bernstein" if mode == "bernstein" else "hoeffding",
+            **_GROUPED)[2].schedule.rounds)
+        r = np.asarray(rounds_used)
+        assert 1 < r[0] < min(r[1:]) and max(r[1:]) <= n_rounds
+
+    @pytest.mark.parametrize("layout", ["shared", "per_query"])
+    def test_coord_mode_groups_bitwise(self, layout):
+        """128-wide coordinate tiles: 64 a group, summed by `f32_tile_dot`
+        on the slab."""
+        from repro.kernels.fused_cascade import pull_group_size
+
+        _, _, plan = _dense_case(pull_mode="coord", **_GROUPED)
+        assert plan.block == 128
+        G = pull_group_size(8, 128, jnp.float32)
+        assert G == 64 and _short_groups(plan, G)
+        dense_parity(layout, 3, "fixed", pull_mode="coord", **_GROUPED)
+
+    def test_final_coverage_steps_group_too(self):
+        """``final_exact``: the final survivors' coverage steps are groups
+        of ``n_final`` tiles; every block of a returned row is summed in
+        permutation order, as a plain scan sums it."""
+        from repro.core.boundedme_jax import (bounded_me_decode,
+                                              fused_flat_schedule)
+        from repro.core.quantize import f32_tile_dot
+
+        V, Q, plan = _dense_case(**dict(_GROUPED, eps=3.0))
+        flat = fused_flat_schedule(plan, final_exact=True)
+        assert flat.t_final == plan.n_blocks > plan.schedule.final_pulls
+        key = jax.random.PRNGKey(5)
+        kw = dict(plan=plan, final_exact=True, k_out=plan.K)
+        ids, vals = bounded_me_decode(V, Q, key, use_pallas=True, **kw)
+        ids_j, _ = bounded_me_decode(V, Q, key, use_pallas=False, **kw)
+        np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids_j))
+        C = plan.block
+        V4 = jnp.asarray(V).reshape(plan.n_tiles, 8, plan.n_blocks, C)
+        Qb = jnp.asarray(Q).reshape(3, plan.n_blocks, C)
+        perm = jax.random.permutation(key, plan.n_blocks)
+
+        def add(s, col):   # (B, n_tiles, R) sums, one block a step
+            return s + f32_tile_dot(V4[None, :, :, col],
+                                    Qb[:, None, col]), None
+        sums = jax.lax.scan(add, jnp.zeros((3, plan.n_tiles, 8)), perm)[0]
+        rows = np.asarray(ids)
+        want = (sums.reshape(3, -1)[jnp.arange(3)[:, None], rows]
+                / jnp.float32(plan.n_blocks * C))
+        np.testing.assert_array_equal(np.asarray(vals), np.asarray(want))
 
 
 def test_cols_must_match_the_dense_layout():

@@ -206,6 +206,35 @@ def test_the_executor_counts_the_plans_pulls_and_the_dense_share():
             [lbl for lbl, _ in dispatches.rows()]
 
 
+@pytest.mark.parametrize("dim,engaged", [(DIM, True), (512, False)])
+def test_the_executor_counts_the_kernels_pull_groups(dim, engaged):
+    """Each dispatch adds lanes x the grid steps that pull a group of up
+    to 16 same-column survivor tiles after round 1, with the dispatch
+    counter's labels; a table one block wide pulls nothing after round
+    1, and counts none."""
+    from repro.core.boundedme_jax import fused_flat_schedule
+
+    ex = CascadeExecutor(_table()[:, :dim], K=1, eps=0.1, delta=0.1,
+                         lanes=LANES)
+    flat = fused_flat_schedule(ex.plan, final_exact=True)
+    size = flat.groups(16)[1]
+    groups = ex.metrics.get("cascade_pull_groups_total")
+    assert groups.total() == 0
+    Q = np.zeros((LANES, dim), np.float32)
+    for k in range(2):
+        ex.dispatch(Q, jax.random.PRNGKey(k))
+    per_lane = int((size > 0).sum())
+    assert groups.total() == 2 * LANES * per_lane
+    assert (per_lane > 0) == engaged
+    if engaged:   # more than one tile a group on average
+        later = (ex.metrics.get("cascade_pulls_total").total()
+                 - ex.metrics.get("cascade_dense_pulls_total").total())
+        assert later == 2 * LANES * int(size.sum()) > groups.total()
+    dispatches = ex.metrics.get("cascade_dispatches_total")
+    assert [lbl for lbl, _ in groups.rows()] == \
+        [lbl for lbl, _ in dispatches.rows()]
+
+
 def _read_bytes_per_dispatch(table, filled=None, **kw):
     """(bytes one dispatch adds to ``cascade_table_read_bytes_total``,
     the executor): a direct dispatch, or one of ``filled`` lanes."""

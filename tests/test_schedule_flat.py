@@ -85,3 +85,70 @@ def test_packed_roundtrip():
     assert meta.shape == (len(sched.rounds) + 1, 3)
     for j, r in enumerate(sched.rounds):
         assert tuple(meta[j]) == (r.t_cum, r.n_arms, r.n_keep)
+
+
+@pytest.mark.parametrize("G", [1, 4, 16, 64])
+@pytest.mark.parametrize("n,N,K,eps,cover", [
+    (50, 40, 2, 0.2, False), (400, 4, 1, 0.05, False),
+    (250, 64, 1, 0.3, True), (7, 100, 3, 0.4, True), (8, 16, 8, 0.1, True)])
+def test_groups_stay_in_one_column_of_one_round(n, N, K, eps, cover, G):
+    """A grid step pulls consecutive slots of one column, opens on a
+    multiple of G, ends before a column change or a round end, and the
+    group sizes sum to the per-query pulls."""
+    from repro.core.schedule import group_steps
+
+    flat = flatten_schedule(make_schedule(n, N, K=K, eps=eps, delta=0.1),
+                            final_coverage=cover)
+    first, size = flat.groups(G)
+    assert first[0] == 0 and (np.diff(first) > 0).all()
+    assert int(size.sum()) == int(flat.is_pull.sum())
+    assert size.max(initial=0) <= G
+    stop = np.r_[first[1:], flat.n_steps]
+    for f, z, e in zip(first, size, stop):
+        if z == 0:                         # a no-pull step stays alone
+            assert e == f + 1 and not flat.is_pull[f]
+            continue
+        run = slice(f, e)
+        assert e - f == z and flat.is_pull[run].all()
+        assert flat.slot[f] % G == 0
+        np.testing.assert_array_equal(flat.slot[run],
+                                      flat.slot[f] + np.arange(z))
+        assert (flat.bpos[run] == flat.bpos[f]).all()       # one column
+        assert (flat.t_cum[run] == flat.t_cum[f]).all()     # one round
+        assert not flat.is_end[f:e - 1].any()   # an end closes a group
+        assert z == min(G, flat.n_surv[f] - flat.slot[f])
+    # the grouped words: each group's first, with its last step's END_BIT
+    code, meta = flat.packed()
+    words, at = group_steps(code, G)
+    np.testing.assert_array_equal(at, first)
+    np.testing.assert_array_equal(words & SLOT_MASK, flat.slot[first])
+    np.testing.assert_array_equal((words & PULL_BIT) != 0, size > 0)
+    np.testing.assert_array_equal((words & END_BIT) != 0,
+                                  flat.is_end[stop - 1] == 1)
+    if G == 1:                             # one tile a step: unchanged
+        np.testing.assert_array_equal(words, code)
+
+
+@pytest.mark.parametrize("rows,d,K,groups,pulls", [
+    (64_000, 8_192, 1, 831, 29_220),     # the Command-R shard
+    (53_248, 1_024, 5, 0, 13_312)])      # Whisper: round 1 pulls it all
+def test_cell_plans_group_their_later_pulls(rows, d, K, groups, pulls):
+    """At G = 16 the Command-R shard's 13,220 pulls after round 1 take 831
+    grid steps (250 + 250 + 189 + 96 + 32 + 8 + 4 + 2) and Whisper's
+    none; the pulls themselves are unchanged."""
+    from repro.core.boundedme_jax import fused_flat_schedule, make_plan
+
+    plan = make_plan(rows, d, K=K, eps=0.1, delta=0.1, value_range=0.24,
+                     tile=8, block=min(512, d))
+    flat = fused_flat_schedule(plan, final_exact=True)
+    first, size = flat.groups(16)
+    assert int((size > 0).sum()) == groups
+    assert flat.pull_steps == pulls
+    if groups:
+        assert flat.dense_pulls == 16_000
+        per_round = [int(size[(size > 0) & (flat.t_cum[first] == r.t_cum)
+                              ].size) for r in plan.schedule.rounds[1:]
+                     if r.t_new]
+        assert per_round == [250, 250, 189, 96, 32, 8, 4, 2]
+    else:
+        assert len(first) == flat.n_steps == 13   # the grid is unchanged
